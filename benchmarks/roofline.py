@@ -122,7 +122,7 @@ def _hlo_collective_bytes(hlo_text: str) -> Dict[str, float]:
 def _step_cell(name: str, shape: str, mesh_name: str, engine,
                state) -> Dict:
     """Lower + compile one engine's jitted step, extract cost/memory."""
-    compiled = engine._jit_step.lower(state, engine._tables).compile()
+    compiled = engine.compile(state)
     cost = compiled.cost_analysis()
     if isinstance(cost, (list, tuple)):
         cost = cost[0] if cost else {}

@@ -25,6 +25,7 @@ from benchmarks import roofline
 from benchmarks import snapshot_bench
 from benchmarks import stream_bench
 from benchmarks import wire_bench
+from repro.launch.compile_cache import use_compile_cache
 
 HARNESSES = {
     "fig1a": pf.fig1a_async_vs_sync_convergence,
@@ -54,6 +55,7 @@ def _write_json(name: str, payload: dict) -> None:
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default=None,
                     help="comma-separated harness names")

@@ -29,6 +29,7 @@ import numpy as np
 from repro.apps.pagerank import PageRankProgram, make_pagerank_graph
 from repro.dist.engine import DistributedEngine
 from repro.graphs.generators import connected_power_law_graph
+from repro.launch.mesh import auto_mesh
 
 N_VERTICES = 400
 TOLERANCE = 1e-10
@@ -40,7 +41,7 @@ MAX_STEPS = 400
 def snapshot_overhead() -> List[Dict]:
     """Fig. 4: sync snapshot flatlines, async computation proceeds."""
     S = jax.device_count()
-    mesh = jax.make_mesh((S, 1), ("data", "model"))
+    mesh = auto_mesh((S, 1), ("data", "model"))
     struct = connected_power_law_graph(N_VERTICES, seed=0)
     g = make_pagerank_graph(struct)
     prog = PageRankProgram(0.15, struct.n_vertices)

@@ -31,6 +31,7 @@ import jax
 import numpy as np
 
 from repro.apps.pagerank import PageRankProgram
+from repro.launch.mesh import auto_mesh
 from repro.stream import (SlackConfig, apply_delta_growing,
                           make_dist_engine, make_local_engine, readback,
                           total_updates)
@@ -125,7 +126,7 @@ def stream_reconvergence() -> List[Dict]:
     S = jax.device_count()
     if S >= 2:
         t0 = time.time()
-        mesh = jax.make_mesh((S, 1), ("data", "model"))
+        mesh = auto_mesh((S, 1), ("data", "model"))
         prefix_g, batches, full_g, in_cap = pagerank_cluster_arrival(
             N_DIST, growth=0.10, alpha=ALPHA, seed=0)
         n_total = full_g.structure.n_vertices

@@ -21,7 +21,7 @@ while color c's local gather⊕combine runs: a jaxpr audit shows the same
 collective count with strictly more collectives issued ahead of gathers
 that do not consume them, at an identical fixed point.
 
-**Kernel.**  The fused scatter/reschedule phase (kernels/gas/scatter.py)
+**Kernel.**  The fused scatter/reschedule phase (kernels/gas/gas.py)
 produces the same priorities as the dense
 ``where(active,0,prio) + segment_sum`` path (≤ 1e-5) across every engine
 that reschedules neighbors — local sweep, chromatic, both distributed
@@ -206,8 +206,9 @@ def _roofline_direction() -> Dict:
             read for the segment sum, dense bump temp ([N+1]·4B
             write+read), prio read/write — every edge, every step.
     fused:  per *active* edge block, senders/receivers/weights block reads
-            + one 4B DMA per live edge; prio/consume/out streamed once;
-            inactive edge blocks cost nothing (the activity bitmap).
+            + one 4B table read per live edge (VMEM-resident table);
+            prio/consume/out read or written once; inactive edge blocks
+            cost nothing (the activity bitmap).
 
     Evaluated at a representative scale (the paper's graphs are 10⁶–10⁸
     edges) with the bench graph's edge/vertex ratio, so the fixed
@@ -232,7 +233,7 @@ def _roofline_direction() -> Dict:
         recs[f"fused_bytes_at_{frac}"] = (
             act_blocks * EDGE_BLOCK * (4 + 4 + 4)
             # senders + receivers + weights of active blocks
-            + int(frac * E) * 4       # one contrib DMA per live edge
+            + int(frac * E) * 4       # one contrib read per live edge
             + 3 * N * 4)              # prio + consume + out
     rec = {"case": "roofline_direction", "n_vertices": N, "n_edges": E,
            "dense_bytes": dense_bytes, **recs}

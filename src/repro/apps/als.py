@@ -98,11 +98,13 @@ def make_als_graph(
     user_of = np.where(s < n_users, s, r)
     movie_of = np.where(s < n_users, r, s) - n_users
     rating = np.einsum("ed,ed->e", u_true[user_of], m_true[movie_of])
-    rating = rating + rng.normal(0, noise, size=rating.shape)
 
-    # train/test split per undirected pair (both directions agree)
+    # noise and train/test split per undirected pair: both directions of a
+    # rating must agree, or the user and movie updates minimize different
+    # objectives and the alternating sweeps never settle
     pair_key = user_of.astype(np.int64) * n_movies + movie_of
     uniq, inv = np.unique(pair_key, return_inverse=True)
+    rating = rating + rng.normal(0, noise, size=uniq.size)[inv]
     is_test_pair = rng.random(uniq.size) < test_frac
     train = (~is_test_pair[inv]).astype(rating.dtype)
 

@@ -32,7 +32,9 @@ from repro.core.graph import DataGraph
 from repro.core.scheduler import SweepScheduler
 from repro.core.sync_op import SyncOp
 from repro.core.update import VertexProgram
-from repro.kernels.gas.ops import EdgeSet
+from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
+from repro.kernels.gas.ops import (EdgeSet, color_runs, size_class,
+                                   split_by_color, stack_edge_sets)
 
 
 class ChromaticEngine(Engine):
@@ -71,25 +73,45 @@ class ChromaticEngine(Engine):
         self.colors = self.scheduler.colors
         self.num_colors = self.scheduler.num_phases
 
-        # Streaming mode skips the per-color edge ranges: the dynamic-
-        # tables path streams the full capacity edge set each phase (the
-        # color mask gates the write-back), since color membership of
-        # *edges* goes stale as deltas land.  The live coloring rides the
-        # dynamic tables instead — delta edges joining same-colored
-        # vertices are repaired at apply_delta time (DESIGN §3.12), so
-        # edge consistency holds between regrows too.
-        self._color_edges: Optional[list] = None
-        if self.use_fused and stream_tables is None:
-            st = graph.structure
-            recv_color = colors[st.receivers]
-            self._color_edges = []
-            for c in range(self.num_colors):
-                idx = np.nonzero(recv_color == c)[0].astype(np.int32)
-                self._color_edges.append(EdgeSet.build(
-                    st.senders[idx], st.receivers[idx], st.n_vertices,
-                    perm=idx))
+    def _phase_edge_sets(self):
+        """Per-color edge ranges (DESIGN.md §3.5).  A color-step gathers
+        over the receiver-sorted edges whose *receiver* has that color, and
+        its reschedule deposits along the edges whose *sender* has it: only
+        that color's vertices execute, so every other sender contributes
+        exactly zero.  Σ_c E_c = E for each, per sweep.  Each set is padded
+        to a power-of-two block count, and runs of consecutive colors of
+        one size class share a grid length, so they stack into one loop.
 
-    def _phase_edges(self, phase: int) -> Optional[EdgeSet]:
-        """Per-color edge range (DESIGN.md §3.5): a color-step streams only
-        the receiver-sorted edges whose receiver has that color."""
-        return self._color_edges[phase] if self._color_edges else None
+        Streaming mode has none: the dynamic-tables path streams the full
+        capacity edge set each phase (the color mask gates the
+        write-back), since color membership of *edges* goes stale as
+        deltas land.  The live coloring rides the dynamic tables instead —
+        delta edges joining same-colored vertices are repaired at
+        apply_delta time (DESIGN §3.12), so edge consistency holds between
+        regrows too."""
+        st = self.structure
+        n, k = st.n_vertices, self.scheduler.num_phases
+        colors = np.asarray(self.scheduler.colors)
+        subsets = {"gather": split_by_color(colors[st.receivers], k),
+                   "scatter": split_by_color(colors[st.senders], k)}
+        blocks = {kind: [size_class(i.size) for i in idx]
+                  for kind, idx in subsets.items()}
+        groups = color_runs(blocks)
+
+        stacked = []
+        for first, count in groups:
+            sets = [{} for _ in range(count)]
+            for kind, idx in subsets.items():
+                nb = blocks[kind][first]
+                pad = lambda i: np.pad(st.receivers[i],
+                                       (0, nb * EDGE_BLOCK - i.size),
+                                       constant_values=n + ROW_BLOCK)
+                n_steps = max(csr_steps(pad(idx[c]), n)[0].size
+                              for c in range(first, first + count))
+                for j, c in enumerate(range(first, first + count)):
+                    sets[j][kind] = EdgeSet.build(
+                        st.senders[idx[c]], st.receivers[idx[c]], n,
+                        perm=idx[c] if kind == "gather" else None,
+                        min_blocks=nb, n_steps=n_steps)
+            stacked.append(stack_edge_sets(sets))
+        return groups, stacked

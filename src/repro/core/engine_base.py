@@ -24,15 +24,15 @@ import jax.numpy as jnp
 
 import numpy as np
 
-from repro.core.graph import DataGraph, csr_block_offsets, segment_combine
+from repro.core.graph import DataGraph, segment_combine
 from repro.core.scheduler import Scheduler, SweepScheduler, reschedule_prio
 from repro.core.sync_op import SyncOp, run_syncs
 from repro.core.update import (EdgeCtx, VertexProgram, edge_ctx,
                                fused_edge_weight, fused_gather_leaves,
                                masked_update, supports_fused_gather)
-from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK
+from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
 from repro.kernels.gas.ops import (EdgeSet, ScatterCtx, active_row_blocks,
-                                   gather_combine)
+                                   gather_combine, stack_edge_sets)
 
 Pytree = Any
 
@@ -208,9 +208,9 @@ def stream_apply_phase(
     to a dropped segment / zero weight, so they contribute exactly nothing.
 
     ``fused_meta`` (from ``Engine._build_stream_fused``) carries the static
-    CSR block metadata of the capacity layout — receivers never move (slot
-    reservation per receiver), so the GAS kernel's block ranges are
-    computed once and only the senders/weights stream through the trace.
+    grid schedule of the capacity layout — receivers never move (slot
+    reservation per receiver), so the GAS kernel's steps are computed once
+    and only the senders/weights stream through the trace.
 
     Returns ``(graph, residual, edges_touched, prio_bump)``.  For
     edge-writing programs, ``prio_bump`` carries the *message residual*
@@ -227,14 +227,13 @@ def stream_apply_phase(
     e_cap = senders.shape[0]
 
     if fused_meta is not None:
-        leaves, treedef, eblk_start, n_eblk, max_eblk, e_pad = fused_meta
+        leaves, treedef, step_rb, step_eb, e_pad = fused_meta
         block_active = active_row_blocks(mask)
         snd = jnp.pad(senders, (0, e_pad - e_cap))
         rcv = jnp.pad(receivers, (0, e_pad - e_cap),
                       constant_values=n + ROW_BLOCK)
-        es = EdgeSet(n_vertices=n, n_edges=e_cap, senders=snd,
-                     receivers=rcv, eblk_start=eblk_start, n_eblk=n_eblk,
-                     max_eblk=max_eblk)
+        es = EdgeSet(n_vertices=n, senders=snd,
+                     receivers=rcv, step_rb=step_rb, step_eb=step_eb)
         src_deg_e = tables["out_deg"][senders] if any(
             leaf.kind == "degree_normalized_src" for leaf in leaves) else None
         acc_leaves = []
@@ -333,8 +332,8 @@ class Engine:
     ``_step`` runs ``scheduler.num_phases`` select → apply → reschedule
     phases (``step`` is its jitted form); subclasses choose the scheduler —
     pass one via ``scheduler=`` or override ``_make_scheduler`` — and may
-    override ``_phase_edges`` to hand each phase its own prepared
-    ``EdgeSet`` (the chromatic per-color edge ranges).
+    override ``_phase_edge_sets`` to hand each phase its own prepared
+    ``EdgeSet``s (the chromatic per-color edge ranges).
 
     ``use_fused`` selects the fused GAS gather⊕combine path (DESIGN.md §3.5)
     for programs that declare registry gathers: None (default) auto-enables
@@ -375,7 +374,6 @@ class Engine:
         self.use_fused = fusable if use_fused is None \
             else bool(use_fused) and fusable
         self.gas_interpret = gas_interpret
-        self._full_edges_cache: Optional[EdgeSet] = None
         self.scheduler = (scheduler if scheduler is not None
                           else self._make_scheduler())
         self._tables: Optional[Dict[str, jnp.ndarray]] = None
@@ -398,6 +396,20 @@ class Engine:
             from repro.obs.config import ObsConfig
             obs = ObsConfig()
         self.obs = obs
+        # per-graph arrays ride the jitted step as arguments — the fused
+        # path's prepared edge sets and a sweep's coloring.  Baked in as
+        # constants, a 10⁷-edge graph would inflate the program, its compile
+        # time and its compile-cache key, and XLA would fold a mask constant
+        # per color-step out of the coloring.
+        static = stream_tables is None
+        self._phase_groups, gas = ([], None)
+        if self.use_fused and static:
+            self._phase_groups, gas = self._phase_edge_sets()
+        self._consts = {
+            "gas": gas,
+            "colors": (self.scheduler.colors
+                       if static and isinstance(self.scheduler,
+                                                SweepScheduler) else None)}
         self._trace_count = 0  # bumped at trace time; delta tests assert 0 new
         self._jit_step = jax.jit(self._step)
 
@@ -425,8 +437,8 @@ class Engine:
 
     def _build_stream_fused(self):
         """Static GAS metadata of the capacity layout: slot reservation per
-        receiver keeps the receiver array frozen, so the CSR block ranges
-        (and the kernel grid) are computed once, here."""
+        receiver keeps the receiver array frozen, so the kernel grid is
+        computed once, here."""
         leaves, treedef = fused_gather_leaves(self.program)
         st = self.structure
         recv = np.asarray(self._tables["receivers"])
@@ -434,48 +446,36 @@ class Engine:
         e_pad = max(-(-e_cap // EDGE_BLOCK), 1) * EDGE_BLOCK
         pad_r = np.int32(st.n_vertices + ROW_BLOCK)
         recv_p = np.pad(recv, (0, e_pad - e_cap), constant_values=pad_r)
-        with jax.ensure_compile_time_eval():
-            start, n_eblk, max_eblk = csr_block_offsets(
-                recv_p, st.n_vertices, ROW_BLOCK, EDGE_BLOCK)
-            return (leaves, treedef, jnp.asarray(start), jnp.asarray(n_eblk),
-                    int(max_eblk), int(e_pad))
+        step_rb, step_eb = csr_steps(recv_p, st.n_vertices)
+        return (leaves, treedef, jnp.asarray(step_rb), jnp.asarray(step_eb),
+                int(e_pad))
 
-    @property
-    def _full_edges(self) -> Optional[EdgeSet]:
-        """Full-graph EdgeSet for fused engines, built on first use.  The
-        chromatic engine gathers through its per-color subsets but still
-        needs this for the fused reschedule scatter (contributions target
-        every out-neighbor, not just the executing color's edges).
+    def _phase_edge_sets(self) -> Tuple[List[Tuple[int, int]], list]:
+        """Prepared EdgeSets of the fused path, per phase: ``gather`` the
+        edges the phase gathers over, ``scatter`` the edges its fused
+        reschedule deposits along (every out-edge of a vertex the phase
+        may execute).  Returns ``(groups, stacked)``: ``groups`` lists
+        ``(first phase, phase count)`` runs of consecutive phases whose
+        sets share shapes, ``stacked`` the run's sets stacked on a leading
+        axis — one entry per phase, or a single entry the run's phases
+        share.  The base engine shares the full structure across all its
+        phases; the chromatic engine restricts each to its color."""
+        st = self.structure
+        full = EdgeSet.build(st.senders, st.receivers, st.n_vertices)
+        return ([(0, self.scheduler.num_phases)],
+                [stack_edge_sets([{"gather": full, "scatter": full}])])
 
-        First use usually happens while tracing ``_step``; without
-        ``ensure_compile_time_eval`` the cached index arrays would be that
-        trace's tracers and leak into any later retrace (``run_while``
-        after ``run``, or a second jit shape)."""
-        if self.use_fused and self._full_edges_cache is None:
-            st = self.structure
-            with jax.ensure_compile_time_eval():
-                self._full_edges_cache = EdgeSet.build(
-                    st.senders, st.receivers, st.n_vertices)
-        return self._full_edges_cache if self.use_fused else None
-
-    # -- the shared phase loop ------------------------------------------------
-    def _phase_edges(self, phase: int) -> Optional[EdgeSet]:
-        """Prepared EdgeSet for one phase (chromatic overrides per color)."""
-        return self._full_edges
-
-    def _scatter_ctx(self, tables) -> Optional[ScatterCtx]:
+    def _scatter_ctx(self, tables, sets) -> Optional[ScatterCtx]:
         """ScatterCtx for the fused reschedule (DESIGN.md §3.14), or None
-        to keep the dense scatter.  Always the FULL edge structure — an
-        executed vertex's contribution targets every out-neighbor, so the
-        chromatic per-color subsets must not be used here.  Gated on f32
-        priorities: the f64 residual opt-in keeps the dense path rather
-        than silently downcasting through the f32 kernel."""
+        to keep the dense scatter.  Gated on f32 priorities: the f64
+        residual opt-in keeps the dense path rather than silently
+        downcasting through the f32 kernel."""
         if not (self.use_fused and self.program.schedule_neighbors):
             return None
         if self.residual_dtype != jnp.float32:
             return None
         if tables is None:
-            return ScatterCtx(edges=self._full_edges,
+            return ScatterCtx(edges=sets["scatter"],
                               interpret=self.gas_interpret)
         if self._stream_fused_meta is None:
             return None
@@ -483,37 +483,39 @@ class Engine:
         # trace (values change, shapes never do); slack slots carry real
         # receiver ids, so the live edge mask must ride as the weights —
         # otherwise a reserved self-loop would bump its own receiver
-        _, _, eblk_start, n_eblk, max_eblk, e_pad = self._stream_fused_meta
+        _, _, step_rb, step_eb, e_pad = self._stream_fused_meta
         n = self.structure.n_vertices
         e_cap = tables["senders"].shape[0]
         es = EdgeSet(
-            n_vertices=n, n_edges=e_cap,
+            n_vertices=n,
             senders=jnp.pad(tables["senders"], (0, e_pad - e_cap)),
             receivers=jnp.pad(tables["receivers"], (0, e_pad - e_cap),
                               constant_values=n + ROW_BLOCK),
-            eblk_start=eblk_start, n_eblk=n_eblk, max_eblk=max_eblk)
+            step_rb=step_rb, step_eb=step_eb)
         w = jnp.pad(tables["edge_mask"].astype(jnp.float32),
                     (0, e_pad - e_cap))
         return ScatterCtx(edges=es, weights=w,
                           interpret=self.gas_interpret)
 
-    def _step(self, state: EngineState, tables=None) -> EngineState:
+    def _step(self, state: EngineState, tables=None,
+              consts=None) -> EngineState:
         self._trace_count += 1
+        if consts is None:
+            consts = self._consts
+        select_tables = tables
+        if tables is None and consts["colors"] is not None:
+            select_tables = {"colors": consts["colors"]}
         prev_vdata = state.graph.vertex_data
-        graph, prio, sched = state.graph, state.prio, state.sched
-        count, total = state.update_count, state.total_updates
-        edges_t = state.edges_touched
         glob = state.globals_
 
-        # unrolled: num_phases is 1 for all but the chromatic sweep, whose
-        # color count is small; the sync op runs safely between phases
-        for phase in range(self.scheduler.num_phases):
+        def run_phase(phase, sets, carry):
+            graph, prio, sched, count, total, edges_t = carry
             mask, sched = self.scheduler.select(sched, prio, phase,
-                                                tables=tables)
+                                                tables=select_tables)
             if tables is None:
                 graph, residual, et = apply_phase(
                     self.program, graph, mask, glob,
-                    edges=self._phase_edges(phase),
+                    edges=sets["gather"] if sets else None,
                     interpret=self.gas_interpret,
                     residual_dtype=self.residual_dtype)
             else:
@@ -524,13 +526,39 @@ class Engine:
                     residual_dtype=self.residual_dtype)
             prio, sched = self.scheduler.reschedule(
                 sched, prio, mask, residual, tables=tables,
-                scatter=self._scatter_ctx(tables))
+                scatter=self._scatter_ctx(tables, sets))
             if tables is not None and bump is not None:
                 prio = prio + bump
-            count = count + mask.astype(jnp.int32)
-            total = total + jnp.sum(mask.astype(jnp.int32))
-            edges_t = edges_t + et
+            return (graph, prio, sched, count + mask.astype(jnp.int32),
+                    total + jnp.sum(mask.astype(jnp.int32)), edges_t + et)
 
+        carry = (state.graph, state.prio, state.sched, state.update_count,
+                 state.total_updates, state.edges_touched)
+        # One phase per color for the chromatic sweep.  Consecutive phases
+        # whose prepared edge sets share a shape run as one loop over the
+        # stacked sets, so a sweep with hundreds of colors traces, lowers
+        # and compiles a handful of phase bodies; the sync op runs safely
+        # between phases.
+        if consts["gas"] is None:
+            for phase in range(self.scheduler.num_phases):
+                carry = run_phase(phase, None, carry)
+        else:
+            for (first, n), stacked in zip(self._phase_groups,
+                                           consts["gas"]):
+                shared = jax.tree.leaves(stacked)[0].shape[0] == 1
+
+                def body(phase, c, first=first, stacked=stacked,
+                         shared=shared):
+                    sets = jax.tree.map(
+                        lambda x: x[0 if shared else phase - first], stacked)
+                    return run_phase(phase, sets, c)
+
+                if n == 1:
+                    carry = body(first, carry)
+                else:
+                    carry = jax.lax.fori_loop(first, first + n, body, carry)
+
+        graph, prio, sched, count, total, edges_t = carry
         state = state.replace(
             graph=graph, prio=prio, sched=sched, update_count=count,
             total_updates=total, edges_touched=edges_t,
@@ -546,7 +574,15 @@ class Engine:
         return state
 
     def step(self, state: EngineState) -> EngineState:
-        return self._jit_step(state, self._tables)
+        return self._jit_step(state, self._tables, self._consts)
+
+    def compile(self, state: EngineState):
+        """Compiles the step ahead of time for ``state``'s shapes; ``step``
+        and ``run`` then call that executable.  Returns it, for its
+        ``as_text()`` and ``memory_analysis()``."""
+        self._jit_step = self._jit_step.lower(
+            state, self._tables, self._consts).compile()
+        return self._jit_step
 
     def _run_syncs(self, state: EngineState, prev_vdata) -> EngineState:
         if not self.sync_ops:
@@ -626,4 +662,5 @@ class Engine:
                 jnp.logical_not(self.scheduler.done(s.sched, s.prio)))
 
         return jax.lax.while_loop(
-            cond, lambda s: self._step(s, self._tables), state)
+            cond, lambda s: self._step(s, self._tables, self._consts),
+            state)

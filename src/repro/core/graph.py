@@ -85,16 +85,24 @@ class GraphStructure:
 
         if sort:
             # receiver-major, sender-minor: receiver blocks are contiguous and
-            # deterministic, which the Pallas segsum kernel relies on.
-            perm = np.lexsort((senders, receivers)).astype(np.int32)
+            # deterministic, which the Pallas kernels rely on.  One stable
+            # argsort of the combined key orders exactly as
+            # lexsort((senders, receivers)), in a fraction of the time.
+            perm = np.argsort(
+                receivers.astype(np.int64) * n_vertices + senders,
+                kind="stable").astype(np.int32)
         else:
             perm = np.arange(senders.size, dtype=np.int32)
         s, r = senders[perm], receivers[perm]
 
         # Reverse-edge lookup: position of (r, s) among receiver-sorted keys.
+        # The queries are searched in sorted order (sequential memory access
+        # instead of E random binary searches), then put back.
         key = r.astype(np.int64) * n_vertices + s.astype(np.int64)
         rev_key = s.astype(np.int64) * n_vertices + r.astype(np.int64)
-        pos = np.searchsorted(key, rev_key)
+        order = np.argsort(rev_key)
+        pos = np.empty(rev_key.size, np.int64)
+        pos[order] = np.searchsorted(key, rev_key[order])
         pos = np.clip(pos, 0, max(key.size - 1, 0))
         has_rev = key.size > 0
         if has_rev:
@@ -140,25 +148,6 @@ class GraphStructure:
             [[0], np.cumsum(np.bincount(self.receivers, minlength=self.n_vertices))]
         ).astype(np.int32)
 
-    def csr_blocks(
-        self,
-        row_block: Optional[int] = None,
-        edge_block: Optional[int] = None,
-    ) -> Tuple[np.ndarray, np.ndarray, int]:
-        """Row-block → edge-block ranges over the receiver-sorted edges.
-
-        The scalar-prefetch metadata of the segsum/GAS kernels: for each
-        ``row_block``-row output block, the first edge block covering it and
-        the number of edge blocks to stream (DESIGN.md §3.5).  Defaults come
-        from the GAS kernel's block constants (deferred import — the
-        kernels package is a leaf, but core loads first)."""
-        if row_block is None or edge_block is None:
-            from repro.kernels.gas import gas as _gas
-            row_block = row_block or _gas.ROW_BLOCK
-            edge_block = edge_block or _gas.EDGE_BLOCK
-        return csr_block_offsets(self.receivers, self.n_vertices,
-                                 row_block, edge_block)
-
     def is_symmetric(self) -> bool:
         return bool(self.n_edges == 0 or (self.reverse_perm >= 0).all())
 
@@ -181,35 +170,6 @@ class GraphStructure:
             "in_degree": jnp.asarray(self.in_degree),
             "out_degree": jnp.asarray(self.out_degree),
         }
-
-
-def csr_block_offsets(
-    receivers: np.ndarray,
-    n_rows: int,
-    row_block: int = 128,
-    edge_block: int = 512,
-) -> Tuple[np.ndarray, np.ndarray, int]:
-    """Host-side: per output row block, (first edge block, #edge blocks).
-
-    ``receivers`` must be non-decreasing; entries >= ``n_rows`` are padding
-    and land past every row block's range.  Returns ``(eblk_start, n_eblk,
-    max_eblk)`` — ``n_eblk`` is always >= 1 so a kernel can use
-    ``j == n_eblk - 1`` as its flush step even for empty row blocks.
-
-    Row blocks that begin past the last edge (edge_pos == E with E an exact
-    ``edge_block`` multiple) would index one block past the end; start/end
-    are clamped to the real block range — the clamped block's receivers all
-    fall outside such a row block, so it contributes nothing."""
-    receivers = np.asarray(receivers)
-    n_edge_blocks = max(-(-receivers.size // edge_block), 1)
-    n_row_blocks = max(-(-n_rows // row_block), 1)
-    bounds = np.arange(n_row_blocks + 1) * row_block
-    edge_pos = np.searchsorted(receivers, bounds)
-    start = np.minimum(edge_pos[:-1] // edge_block, n_edge_blocks - 1)
-    end = np.minimum(np.maximum(-(-edge_pos[1:] // edge_block), start + 1),
-                     n_edge_blocks)
-    n_eblk = np.maximum(end - start, 1).astype(np.int32)
-    return start.astype(np.int32), n_eblk, int(n_eblk.max(initial=1))
 
 
 # ---------------------------------------------------------------------------
@@ -287,16 +247,21 @@ def segment_combine(
     JAX has no CSR SpMM; this segment-op formulation *is* the system's sparse
     layer.  ``combiner`` ∈ {sum, mean, max, min}.  When the caller holds the
     *host* receiver array (static structure) and passes it as
-    ``receivers_np``, the sorted sum path dispatches to the Pallas segsum
-    kernel on TPU (DESIGN.md §3.5); everywhere else it stays a segment op.
+    ``receivers_np``, a sorted 2-D sum whose width the segsum kernel takes
+    (a multiple of 128 lanes: ``kernel_takes_width``) dispatches to the
+    Pallas kernel on TPU (DESIGN.md §3.5); narrower sums, where the
+    kernel's lane padding would multiply the traffic, and everything off
+    the TPU stay a segment op.
     """
 
     def _one(m):
         if combiner == "sum":
             if (receivers_np is not None and indices_are_sorted
                     and m.ndim == 2 and jax.default_backend() == "tpu"):
-                from repro.kernels.segsum.ops import segment_sum_sorted
-                return segment_sum_sorted(m, receivers_np, n_vertices)
+                from repro.kernels.segsum.ops import (kernel_takes_width,
+                                                      segment_sum_sorted)
+                if kernel_takes_width(m.shape[1]):
+                    return segment_sum_sorted(m, receivers_np, n_vertices)
             return jax.ops.segment_sum(
                 m, receivers, n_vertices, indices_are_sorted=indices_are_sorted)
         if combiner == "mean":

@@ -273,9 +273,13 @@ def atom_meta_index(st: GraphStructure, atom_of: np.ndarray) -> AtomIndex:
     src_atom = atom_of[st.senders]
     cutmask = e_atom != src_atom
     if cutmask.any():
-        up, w = np.unique(np.stack([src_atom[cutmask], e_atom[cutmask]], 1),
-                          axis=0, return_counts=True)
-        meta_src, meta_dst, meta_w = up[:, 0], up[:, 1], w.astype(np.int64)
+        # one int64 key per cut edge: a 1-D unique is far cheaper than a
+        # row-wise one, and sorts the (src, dst) pairs the same way
+        key, w = np.unique(src_atom[cutmask].astype(np.int64) * k
+                           + e_atom[cutmask], return_counts=True)
+        meta_src = (key // k).astype(np.int32)
+        meta_dst = (key % k).astype(np.int32)
+        meta_w = w.astype(np.int64)
     else:
         meta_src = meta_dst = np.zeros(0, np.int32)
         meta_w = np.zeros(0, np.int64)

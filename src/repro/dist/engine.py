@@ -37,6 +37,7 @@ updates).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -47,10 +48,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.coloring import coloring_for
 from repro.core.engine_base import edge_residual_bump
-from repro.core.graph import DataGraph, csr_block_offsets, segment_combine
+from repro.core.graph import DataGraph, segment_combine
 from repro.core.scheduler import sweep_mask
 from repro.core.snapshot import SnapshotState, stitch_rows
-from repro.dist.compat import shard_map
 from repro.dist.snapshot import (assemble_snapshot as _assemble_snapshot,
                                  init_dist_snapshot, make_marker_phase,
                                  mark_stale)
@@ -63,9 +63,10 @@ from repro.core.update import (EdgeCtx, VertexProgram, fused_edge_weight,
 from repro.dist.wire import (WireConfig, decode_payload, encode_payload,
                              encode_rows, payload_row_nbytes,
                              tree_add_where, tree_rows_maxabs, tree_sub)
-from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK
-from repro.kernels.gas.ops import (EdgeSet, active_row_blocks,
-                                   gather_combine, scatter_reschedule)
+from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
+from repro.kernels.gas.ops import (EdgeSet, active_row_blocks, color_runs,
+                                   gather_combine, scatter_reschedule,
+                                   size_class, split_by_color)
 
 Pytree = Any
 
@@ -467,18 +468,20 @@ class ShardEngineBase:
                  + lay.tables["esend_idx"][ent]] = True
         lay.tables["ehas_cacher"] = ehas
 
-        # Fused GAS local compute (DESIGN.md §3.5): per-machine CSR block
-        # metadata over the *local* edge rows.  Within a machine the real
-        # edge rows keep the global receiver-sorted order and local receiver
-        # ids are monotone in global ids, so the local receiver array is
-        # sorted; pad rows route past every row block.  Same knobs as the
+        # Fused GAS local compute (DESIGN.md §3.5): per-machine kernel grid
+        # schedule over the *local* edge rows, padded to one shared length.
+        # Within a machine the real edge rows keep the global receiver-sorted
+        # order and local receiver ids are monotone in global ids, so the
+        # local receiver array is sorted; pad rows route past every row
+        # block.  Same knobs as the
         # shared-memory engines: use_fused=False forces the seed dense
         # shard_map body, gas_interpret=True runs the kernel body on CPU.
         fusable = supports_fused_gather(program)
         self._use_fused = fusable if use_fused is None \
             else bool(use_fused) and fusable
         self._gas_interpret = gas_interpret
-        self._gas_max_eblk = 0
+        # per-color local edge sets of the sweep engine (_build_color_sets)
+        self._color_sets = self._color_groups = None
         if self._use_fused:
             self._gas_leaves, self._gas_treedef = fused_gather_leaves(program)
             lay = self.layout
@@ -492,19 +495,15 @@ class ShardEngineBase:
                         ((0, 0), (0, e_pad - e_loc)), constant_values=pad_r)
             sk = np.pad(np.where(em, sl, 0).astype(np.int32),
                         ((0, 0), (0, e_pad - e_loc)))
-            starts, neblks = [], []
             for m in range(S):
                 assert (np.diff(rk[m]) >= 0).all(), \
                     "local receivers must be sorted for the GAS kernel"
-                st_m, ne_m, mx = csr_block_offsets(
-                    rk[m], n_loc, ROW_BLOCK, EDGE_BLOCK)
-                starts.append(st_m)
-                neblks.append(ne_m)
-                self._gas_max_eblk = max(self._gas_max_eblk, mx)
+            n_steps = max(csr_steps(rk[m], n_loc)[0].size for m in range(S))
+            steps = [csr_steps(rk[m], n_loc, n_steps) for m in range(S)]
             lay.tables["gas_send"] = sk.reshape(-1)
             lay.tables["gas_recv"] = rk.reshape(-1)
-            lay.tables["gas_start"] = np.concatenate(starts).astype(np.int32)
-            lay.tables["gas_neblk"] = np.concatenate(neblks).astype(np.int32)
+            lay.tables["gas_step_rb"] = np.concatenate([a for a, _ in steps])
+            lay.tables["gas_step_eb"] = np.concatenate([b for _, b in steps])
 
         if self.streaming:
             # The GAS metadata above was built over the *allocated* capacity
@@ -676,7 +675,6 @@ class ShardEngineBase:
         use_fused = self._use_fused
         if use_fused:
             gas_leaves, gas_treedef = self._gas_leaves, self._gas_treedef
-            gas_max_eblk = self._gas_max_eblk
             gas_interpret = self._gas_interpret
         wire_cfg = self.wire
         codec = wire_cfg.codec
@@ -701,7 +699,31 @@ class ShardEngineBase:
             recv_changed = a2a(ship)
             return recv, recv_changed, jnp.sum(ship, dtype=jnp.int32)
 
-        def phase_update(tb, carry, active, defer=False):
+        def color_edge_sets(tb, color, group):
+            """The color-step's local edge sets: gather over edges whose
+            receiver has the color, deposit along edges whose sender has
+            it (only those senders contribute), plus the gather subset's
+            rows in the local edge arrays.  ``color`` may be traced: the
+            colors of one group have equal sizes, laid end to end."""
+            first = self._color_groups[group][0]
+            cut = jax.lax.dynamic_slice_in_dim
+            sets, perm = [], None
+            for kind in ("g", "s"):
+                e_base, size, t_base, n_steps = self._color_sets[kind][group]
+                lo = e_base + (color - first) * size
+                tlo = t_base + (color - first) * n_steps
+                sets.append(EdgeSet(
+                    n_vertices=n_loc,
+                    senders=cut(tb[f"gasc_{kind}_send"], lo, size),
+                    receivers=cut(tb[f"gasc_{kind}_recv"], lo, size),
+                    step_rb=cut(tb[f"gasc_{kind}_srb"], tlo, n_steps),
+                    step_eb=cut(tb[f"gasc_{kind}_seb"], tlo, n_steps)))
+                if kind == "g":
+                    perm = cut(tb["gasc_g_perm"], lo, size)
+            return sets[0], sets[1], perm
+
+        def phase_update(tb, carry, active, defer=False, color=None,
+                         group=None):
             # a stalled machine (membership: dead or hung) executes no
             # updates — and, through the versioned exchange below, ships
             # nothing, so poisoned data never leaves it (DESIGN §3.13)
@@ -750,12 +772,16 @@ class ShardEngineBase:
                 # own+ghost rows, per-edge scalar weight, one GAS
                 # gather⊕combine per leaf — no [e_loc, D] messages, and
                 # row blocks with no scheduled own vertex are skipped.
-                # ``es`` is reused below by the fused reschedule scatter.
+                # ``es_dep`` is reused below by the fused reschedule
+                # scatter.  A sweep's color-step streams only its color's
+                # edges (see color_edge_sets); otherwise all local edges.
                 es = EdgeSet(
-                    n_vertices=n_loc, n_edges=e_loc,
+                    n_vertices=n_loc,
                     senders=tb["gas_send"], receivers=tb["gas_recv"],
-                    eblk_start=tb["gas_start"], n_eblk=tb["gas_neblk"],
-                    max_eblk=gas_max_eblk)
+                    step_rb=tb["gas_step_rb"], step_eb=tb["gas_step_eb"])
+                es_dep, perm = es, None
+                if group is not None:
+                    es, es_dep, perm = color_edge_sets(tb, color, group)
                 blk_active = active_row_blocks(active)
                 accs = []
                 for leaf in gas_leaves:
@@ -764,6 +790,8 @@ class ShardEngineBase:
                     w = fused_edge_weight(leaf, edata, e_loc,
                                           tb["src_deg_e"])
                     w = jnp.where(tb["edge_mask"], w, 0.0)
+                    if perm is not None:
+                        w = w[perm]
                     a = gather_combine(
                         feat.reshape(feat.shape[0], -1), w, es,
                         block_active=blk_active,
@@ -941,9 +969,10 @@ class ShardEngineBase:
             if prog.schedule_neighbors:
                 contrib_all = jnp.concatenate([contrib, ghost_contrib])
                 if use_fused:
+                    # a color subset holds only live edges: unit weights
                     prio = scatter_reschedule(
-                        contrib_all, prio, active, es,
-                        emask.astype(jnp.float32),
+                        contrib_all, prio, active, es_dep,
+                        emask.astype(jnp.float32) if es_dep is es else None,
                         interpret=gas_interpret)
                 else:
                     prio = jnp.where(active, 0.0, prio)
@@ -1136,7 +1165,7 @@ class ShardEngineBase:
             traffic_r=spec, traffic_bytes_v=spec, traffic_bytes_e=spec,
             traffic_bytes_r=spec, step_index=P(), snap=spec, globals_=P(),
             beats=spec, wire=spec)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             full_body, mesh=self.mesh,
             in_specs=(state_specs, spec), out_specs=state_specs,
             check_vma=False)
@@ -1154,6 +1183,13 @@ class ShardEngineBase:
     # -- drivers --------------------------------------------------------------
     def step(self, state: DistState) -> DistState:
         return self._jit_step(state, self._tables)
+
+    def compile(self, state: DistState):
+        """Compiles the step ahead of time for ``state``'s shapes; ``step``
+        and ``run`` then call that executable.  Returns it, for its
+        ``as_text()`` and ``memory_analysis()``."""
+        self._jit_step = self._jit_step.lower(state, self._tables).compile()
+        return self._jit_step
 
     def run(self, state: DistState, max_steps: int = 100, *,
             trace_every: Optional[int] = None,
@@ -1387,7 +1423,76 @@ class DistributedEngine(ShardEngineBase):
         ok = self.layout.own_gid >= 0
         colors_own[ok] = colors[self.layout.own_gid[ok]]
         self.layout.tables["colors_own"] = colors_own
+        if self._use_fused and not self.streaming:
+            self._build_color_sets(colors)
         self._finalize()
+
+    def _build_color_sets(self, colors: np.ndarray) -> None:
+        """Per-color local edge sets for the fused sweep (the dist twin of
+        ``ChromaticEngine._phase_edge_sets``): without them every
+        color-step streams all local edges, and a power-law graph has
+        colors in the hundreds.  Each (kind, color) set is padded to a
+        power-of-two block count shared by all machines; runs of
+        consecutive colors of one size class (``_color_groups``) share a
+        grid length too, so a sweep loops over them.  All sets lie end to
+        end in one table per array; ``_color_sets[kind][group]`` keeps the
+        group's static (edge base, set size, step base, steps).  Streaming
+        engines keep the full set: color membership of edges goes stale
+        as deltas land."""
+        lay = self.layout
+        S, n_loc, e_loc = lay.n_machines, lay.n_loc, lay.e_loc
+        ghosts = lay.ghost_gid.reshape(S, -1)
+        local_colors = np.concatenate(
+            [lay.tables["colors_own"].reshape(S, n_loc),
+             np.where(ghosts >= 0, colors[np.maximum(ghosts, 0)], -1)],
+            axis=1)
+        sl = lay.tables["senders_local"].reshape(S, e_loc)
+        rl = lay.tables["receivers_local"].reshape(S, e_loc)
+        em = lay.tables["edge_mask"].reshape(S, e_loc)
+        k = self.num_colors
+        subsets = {}
+        for kind, key in (("g", np.take_along_axis(local_colors, rl, 1)),
+                          ("s", np.take_along_axis(local_colors, sl, 1))):
+            key = np.where(em, key, -1)       # padding rows join no color
+            per_machine = [split_by_color(key[m], k) for m in range(S)]
+            subsets[kind] = [[per_machine[m][c] for m in range(S)]
+                             for c in range(k)]
+        blocks = {kind: [size_class(max(i.size for i in idx))
+                         for idx in per_color]
+                  for kind, per_color in subsets.items()}
+        self._color_groups = color_runs(blocks)
+        self._color_sets = {}
+        pad_r = n_loc + ROW_BLOCK
+        for kind, per_color in subsets.items():
+            cols = {f: [[] for _ in range(S)]
+                    for f in ("send", "recv", "perm", "srb", "seb")}
+            ranges, e_off, t_off = [], 0, 0
+            for first, count in self._color_groups:
+                size = blocks[kind][first] * EDGE_BLOCK
+                recv = [[np.pad(rl[m][i], (0, size - i.size),
+                                constant_values=pad_r)
+                         for m, i in enumerate(per_color[c])]
+                        for c in range(first, first + count)]
+                n_steps = max(csr_steps(r, n_loc)[0].size
+                              for rc in recv for r in rc)
+                for j, c in enumerate(range(first, first + count)):
+                    for m, i in enumerate(per_color[c]):
+                        pad = (0, size - i.size)
+                        cols["send"][m].append(np.pad(sl[m][i], pad))
+                        cols["recv"][m].append(recv[j][m])
+                        cols["perm"][m].append(np.pad(i, pad))
+                        srb, seb = csr_steps(recv[j][m], n_loc, n_steps)
+                        cols["srb"][m].append(srb)
+                        cols["seb"][m].append(seb)
+                ranges.append((e_off, size, t_off, n_steps))
+                e_off += count * size
+                t_off += count * n_steps
+            self._color_sets[kind] = ranges
+            for f, per_machine in cols.items():
+                if kind == "s" and f == "perm":
+                    continue
+                lay.tables[f"gasc_{kind}_{f}"] = np.concatenate(
+                    [np.concatenate(p) for p in per_machine]).astype(np.int32)
 
     def _clone_kwargs(self) -> dict:
         return dict(super()._clone_kwargs(), colors=self.colors,
@@ -1397,6 +1502,7 @@ class DistributedEngine(ShardEngineBase):
         _, phase_update = self._make_phase_helpers()
         num_colors, tol = self.num_colors, self.tolerance
         overlap = self.overlap
+        groups = self._color_groups
 
         def body(state: DistState, tb: Dict[str, jnp.ndarray]) -> DistState:
             carry = dict(vown=state.vown, vghost=state.vghost,
@@ -1414,12 +1520,29 @@ class DistributedEngine(ShardEngineBase):
             # color never defers, so no packet outlives the step and the
             # run() termination check stays exact.
             defer_ok = overlap and state.snap is None
-            for c in range(num_colors):
+
+            def color_step(c, carry, group=None):
                 active = jnp.logical_and(
                     tb["own_mask"],
                     sweep_mask(tb["colors_own"], carry["prio"], tol, c))
-                carry = phase_update(tb, carry, active,
-                                     defer=defer_ok and c < num_colors - 1)
+                return phase_update(tb, carry, active,
+                                    defer=defer_ok and c < num_colors - 1,
+                                    color=c, group=group)
+
+            if groups is None or defer_ok:
+                # (a deferred exchange changes the carry from phase to
+                # phase, so overlapped sweeps stay unrolled)
+                group_of = {c: g for g, (first, n) in enumerate(groups or [])
+                            for c in range(first, first + n)}
+                for c in range(num_colors):
+                    carry = color_step(c, carry, group_of.get(c))
+            else:
+                # runs of same-sized colors loop over one traced body
+                for g, (first, n) in enumerate(groups):
+                    step_g = functools.partial(color_step, group=g)
+                    carry = (step_g(first, carry) if n == 1 else
+                             jax.lax.fori_loop(first, first + n, step_g,
+                                               carry))
             return DistState(
                 vown=carry["vown"], vghost=carry["vghost"],
                 edata=carry["edata"], eghost=carry["eghost"],

@@ -1,132 +1,101 @@
 """Pallas TPU kernel: receiver-sorted segment sum (blocked SpMM-style).
 
-The ⊕-combine of the GraphLab engines and every GNN arch: accumulate
-per-edge messages into per-vertex rows.  TPU-native design:
+The ⊕-combine of the GraphLab engines' dense path and every GNN arch:
+accumulate per-edge messages into per-vertex rows.  TPU-native design:
 
   - receivers are sorted (the data graph stores edges receiver-major), so
-    the edges of a 128-row output block are a *contiguous* edge range —
-    computed on host and passed as scalar-prefetch block offsets;
-  - the in-block scatter is a one-hot MXU matmul: onehot[RB, EB] @
-    msgs[EB, D] — scatter-by-matrix-multiply is the idiomatic way to feed
-    the 128x128 systolic array an irregular reduce;
-  - grid (row_block i, edge_block j, feat_block k), j sequential: a VMEM
-    accumulator per (i, k) is revisited across j (TPU grids execute
-    sequentially on core) and flushed once at j == n_eblocks(i)-1;
+    the edges of a 128-row output block are a *contiguous* run of edge
+    blocks; the grid walks the same flattened (row block, edge block)
+    schedule as the GAS kernels (``kernels/gas/gas.py:csr_steps``), with
+    the two per-step ids as scalar prefetch;
+  - the in-block scatter is a one-hot MXU matmul at ``Precision.HIGHEST``:
+    onehot[RB, EB] @ msgs[EB, 128] — scatter-by-matrix-multiply is the
+    idiomatic way to feed the 128x128 systolic array an irregular reduce,
+    and with an exact one-hot factor f32 messages are not rounded to bf16;
+  - grid (feature block k, step t), t innermost and sequential: the VMEM
+    accumulator of one (row block, feature block) pair is reset at the row
+    block's first step and flushed at its last;
   - boundary edge blocks are shared by adjacent row blocks; the row-range
     mask makes each contribution exactly-once.
 
-VMEM per step: msgs EB*BD*4 + onehot RB*EB*4 + acc RB*BD*4 ~= 1.3 MB at
-(RB, EB, BD) = (128, 512, 128) — comfortably under the 16 MB budget with
-double buffering.
+VMEM per step: msgs EB*128*4 + onehot RB*EB*4 + acc RB*128*4 ≈ 1.1 MB.
 """
 from __future__ import annotations
 
-import functools
-from typing import Tuple
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-ROW_BLOCK = 128
-EDGE_BLOCK = 512
+from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK
+
 FEAT_BLOCK = 128
 
 
-def _kernel(eblk_start_ref, n_eblk_ref,      # scalar prefetch [n_row_blocks]
-            msgs_ref, recv_ref,              # inputs (blocked)
-            out_ref,                         # output block [RB, BD]
-            acc_ref):                        # VMEM scratch [RB, BD] f32
-    # grid (i, k, j): edge blocks j INNERMOST so the accumulator for one
-    # (row block, feature block) pair is contiguous in the sequential grid
-    i = pl.program_id(0)
-    j = pl.program_id(2)
+def _kernel(srb, seb,                      # scalar prefetch [T], [T]
+            msgs_ref, recv_ref,            # blocks [EB, BD], [EB]
+            out_ref,                       # block [RB, BD]
+            acc_ref):                      # VMEM scratch [RB, BD] f32
+    t = pl.program_id(1)
+    n_t = pl.num_programs(1)
+    rb = srb[t]
 
-    @pl.when(j == 0)
+    @pl.when((t == 0) | (srb[jnp.maximum(t - 1, 0)] != rb))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    n_eblk = n_eblk_ref[i]
-
-    @pl.when(j < n_eblk)
+    @pl.when(seb[t] >= 0)
     def _accumulate():
-        row_lo = i * ROW_BLOCK
-        recv = recv_ref[...]                                  # [EB]
-        local = recv - row_lo
-        valid = (local >= 0) & (local < ROW_BLOCK)
+        local = recv_ref[...] - rb * ROW_BLOCK
         rows = jax.lax.broadcasted_iota(jnp.int32, (ROW_BLOCK, EDGE_BLOCK), 0)
-        onehot = jnp.where(
-            valid[None, :] & (rows == local[None, :]), 1.0, 0.0)
-        msgs = msgs_ref[...].astype(jnp.float32)              # [EB, BD]
+        onehot = jnp.where(rows == local[None, :], 1.0, 0.0)
         acc_ref[...] += jax.lax.dot_general(
-            onehot, msgs, (((1,), (0,)), ((), ())),
+            onehot, msgs_ref[...].astype(jnp.float32),
+            (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32)
 
-    @pl.when(j == jnp.maximum(n_eblk, 1) - 1)
+    @pl.when((t == n_t - 1) | (srb[jnp.minimum(t + 1, n_t - 1)] != rb))
     def _flush():
         out_ref[...] = acc_ref[...].astype(out_ref.dtype)
-
-
-def block_offsets(receivers: np.ndarray, n_rows: int,
-                  n_edges: int) -> Tuple[np.ndarray, np.ndarray, int]:
-    """Host-side: per output row block, (first edge block, #edge blocks).
-
-    Clamped to the real edge-block range: a row block beginning past the
-    last edge (edge_pos == n_edges with n_edges an exact EDGE_BLOCK
-    multiple) must not index one block past the end — the clamped block's
-    receivers fall outside the row block and contribute nothing."""
-    n_edge_blocks = max(pl.cdiv(n_edges, EDGE_BLOCK), 1)
-    n_row_blocks = pl.cdiv(n_rows, ROW_BLOCK)
-    bounds = np.arange(n_row_blocks + 1) * ROW_BLOCK
-    edge_pos = np.searchsorted(receivers, bounds)
-    start = np.minimum(edge_pos[:-1] // EDGE_BLOCK, n_edge_blocks - 1)
-    end = np.minimum(np.maximum(pl.cdiv(edge_pos[1:], EDGE_BLOCK), start + 1),
-                     n_edge_blocks)
-    n_eblk = np.maximum(end - start, 1).astype(np.int32)
-    return start.astype(np.int32), n_eblk, int(n_eblk.max(initial=1))
 
 
 def segment_sum_sorted_pallas(
     msgs: jnp.ndarray,
     receivers: jnp.ndarray,
     n_rows: int,
-    eblk_start: jnp.ndarray,
-    n_eblk: jnp.ndarray,
-    max_eblk: int,
+    step_rb: jnp.ndarray,
+    step_eb: jnp.ndarray,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """msgs [E, D] (E % EDGE_BLOCK == 0), receivers [E] sorted (pad = n_rows
-    or anything >= n_rows), -> [n_rows_padded, D]."""
+    """msgs [E, D] (E % EDGE_BLOCK == 0), receivers [E] sorted (pads >=
+    n_rows + ROW_BLOCK), (step_rb, step_eb) from ``csr_steps`` →
+    [n_rows_padded, D]."""
     E, D = msgs.shape
     assert E % EDGE_BLOCK == 0, (E,)
     n_pad_rows = pl.cdiv(n_rows, ROW_BLOCK) * ROW_BLOCK
-    n_row_blocks = n_pad_rows // ROW_BLOCK
     d_pad = pl.cdiv(D, FEAT_BLOCK) * FEAT_BLOCK
     if d_pad != D:
         msgs = jnp.pad(msgs, ((0, 0), (0, d_pad - D)))
-    grid = (n_row_blocks, d_pad // FEAT_BLOCK, max_eblk)
+    eblk = lambda k, t, srb, seb: (jnp.maximum(seb[t], 0), k)
 
     out = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=grid,
+            grid=(d_pad // FEAT_BLOCK, step_rb.shape[0]),
             in_specs=[
-                pl.BlockSpec(
-                    (EDGE_BLOCK, FEAT_BLOCK),
-                    lambda i, k, j, s, n: (
-                        s[i] + jnp.minimum(j, n[i] - 1), k)),
-                pl.BlockSpec(
-                    (EDGE_BLOCK,),
-                    lambda i, k, j, s, n: (s[i] + jnp.minimum(j, n[i] - 1),)),
+                pl.BlockSpec((EDGE_BLOCK, FEAT_BLOCK), eblk),
+                pl.BlockSpec((EDGE_BLOCK,),
+                             lambda k, t, srb, seb: (jnp.maximum(seb[t], 0),)),
             ],
             out_specs=pl.BlockSpec((ROW_BLOCK, FEAT_BLOCK),
-                                   lambda i, k, j, s, n: (i, k)),
+                                   lambda k, t, srb, seb: (srb[t], k)),
             scratch_shapes=[pltpu.VMEM((ROW_BLOCK, FEAT_BLOCK), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((n_pad_rows, d_pad), msgs.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(eblk_start, n_eblk, msgs, receivers)
+    )(step_rb.astype(jnp.int32), step_eb.astype(jnp.int32), msgs,
+      receivers.astype(jnp.int32))
     return out[:, :D] if d_pad != D else out

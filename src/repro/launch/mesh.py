@@ -9,10 +9,19 @@ from __future__ import annotations
 import jax
 
 
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with ``Auto`` axes: the engines and the logical
+    sharding rules place data with ``shard_map`` and
+    ``with_sharding_constraint``, which refuse ``Explicit`` axes — the
+    default of ``jax.make_mesh``."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 # TPU v5e hardware constants (per chip) — §Roofline sources

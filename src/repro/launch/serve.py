@@ -19,6 +19,7 @@ import numpy as np
 
 from repro.configs.registry import get_arch
 from repro.dist.sharding import SERVE_RULES
+from repro.launch.compile_cache import use_compile_cache
 
 
 def serve_lm(cfg, batch: int, prompt_len: int, gen: int,
@@ -81,6 +82,7 @@ def serve_lm(cfg, batch: int, prompt_len: int, gen: int,
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

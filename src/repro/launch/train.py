@@ -23,6 +23,7 @@ from repro.checkpoint.manager import CheckpointManager, young_interval
 from repro.configs.registry import get_arch
 from repro.data.pipeline import lm_batches, dlrm_batches, gnn_batch
 from repro.dist.sharding import TRAIN_RULES
+from repro.launch.compile_cache import use_compile_cache
 from repro.optim.adamw import (adamw_init, adamw_update, clip_by_global_norm,
                                cosine_schedule)
 
@@ -127,6 +128,7 @@ def train_dlrm(cfg, steps: int, batch: int = 256, log_every: int = 10):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

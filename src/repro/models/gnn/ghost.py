@@ -219,6 +219,5 @@ def ghost_loss_fn(cfg, mod, gnn_loss, mesh, plan: GhostPlan):
             "send_idx": P(dp_spec), "send_mask": P(dp_spec),
         },
     )
-    from repro.dist.compat import shard_map
-    return shard_map(body, mesh=mesh, in_specs=in_specs,
-                     out_specs=P(), check_vma=False)
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)

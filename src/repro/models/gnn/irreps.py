@@ -174,7 +174,6 @@ def align_to_z(r: jnp.ndarray) -> jnp.ndarray:
     n = jnp.maximum(n, 1e-12)
     x, y, z = x / n, y / n, z / n
     # axis v = r_hat x z = (y, -x, 0); cos = z
-    c = z
     eye = jnp.broadcast_to(jnp.eye(3, dtype=r.dtype), r.shape[:-1] + (3, 3))
     vx, vy = y, -x
     zero = jnp.zeros_like(x)
@@ -183,12 +182,18 @@ def align_to_z(r: jnp.ndarray) -> jnp.ndarray:
         jnp.stack([zero, zero, -vx], -1),
         jnp.stack([-vy, vx, zero], -1),
     ], -2)
-    denom = jnp.maximum(1.0 + c, 1e-6)[..., None, None]
-    R = eye + K + (K @ K) / denom
-    # antipodal fallback: rotate pi about x: (x,y,z) -> (x,-y,-z)
+    # 1 + cos; near the antipode 1 + z cancels, and the equal form
+    # (x² + y²) / (1 - z) keeps full precision
+    s2 = x * x + y * y
+    one_plus_c = jnp.where(z >= 0, 1.0 + z,
+                           s2 / jnp.maximum(1.0 - z, 1e-12))
+    antipode = s2 <= 0
+    R = eye + K + (K @ K) / jnp.where(antipode, 1.0, one_plus_c)[
+        ..., None, None]
+    # exact antipode: rotate pi about x: (x,y,z) -> (x,-y,-z)
     flip = jnp.asarray([[1., 0., 0.], [0., -1., 0.], [0., 0., -1.]], r.dtype)
     flip = jnp.broadcast_to(flip, R.shape)
-    use_flip = (c < -1.0 + 1e-6)[..., None, None]
+    use_flip = (antipode & (z < 0))[..., None, None]
     return jnp.where(use_flip, flip, R)
 
 

@@ -181,8 +181,7 @@ def _moe_shard_map(cfg, rules, mesh, x, mlp):
     dspec = P(dp if len(dp) > 1 else dp[0], None, None)
     espec = P(dp if len(dp) > 1 else dp[0], None, tp_spec)
     dnspec = P(dp if len(dp) > 1 else dp[0], tp_spec, None)
-    from repro.dist.compat import shard_map
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(dspec, P(None, None), espec, espec, dnspec),
         out_specs=(dspec, P()),

@@ -88,8 +88,10 @@ def cpu_mesh():
     """A (data=N, model=1) CPU mesh over every forced host device."""
     import jax
 
+    from repro.launch.mesh import auto_mesh
+
     n = jax.device_count()
-    mesh = jax.make_mesh((n, 1), ("data", "model"))
+    mesh = auto_mesh((n, 1), ("data", "model"))
     return mesh
 
 

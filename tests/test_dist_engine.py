@@ -49,6 +49,23 @@ class TestFixedPointParity:
         exact = exact_pagerank(st, 0.15, iters=500)
         assert np.abs(out - exact).max() <= 1e-4
 
+    def test_pagerank_kernel_interpret_matches_chromatic(
+            self, cpu_mesh, small_power_law):
+        """The Pallas kernel bodies (interpret mode) inside the sweep
+        engine, over its per-color local edge sets."""
+        st = small_power_law
+        g = make_pagerank_graph(st)
+        prog = PageRankProgram(0.15, st.n_vertices)
+        ce = ChromaticEngine(prog, g, tolerance=1e-6)
+        de = DistributedEngine(prog, g, cpu_mesh, tolerance=1e-6,
+                               colors=np.asarray(ce.colors),
+                               gas_interpret=True)
+        assert de._color_sets is not None
+        cs, _ = ce.run(ce.init(g), max_steps=300)
+        ds, _ = de.run(de.init(), max_steps=300)
+        ref = np.asarray(cs.graph.vertex_data["rank"])
+        assert np.abs(de.vertex_data(ds)["rank"] - ref).max() <= 1e-5
+
     def test_pagerank_update_counts_match(self, cpu_mesh, small_power_law):
         st = small_power_law
         g = make_pagerank_graph(st)

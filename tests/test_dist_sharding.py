@@ -6,6 +6,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import (SERVE_RULES, TRAIN_RULES, AxisRules,
                                  logical_spec, shard_constraint)
+from repro.launch.mesh import auto_mesh
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 4, reason="needs 4 forced host devices")
@@ -13,12 +14,12 @@ pytestmark = pytest.mark.skipif(
 
 @pytest.fixture(scope="module")
 def data_mesh():
-    return jax.make_mesh((4, 1), ("data", "model"))
+    return auto_mesh((4, 1), ("data", "model"))
 
 
 @pytest.fixture(scope="module")
 def model_mesh():
-    return jax.make_mesh((1, 4), ("data", "model"))
+    return auto_mesh((1, 4), ("data", "model"))
 
 
 class TestResolution:
@@ -56,7 +57,7 @@ class TestDivisibilityFallback:
     def test_multi_axis_prefix_fallback(self):
         if jax.device_count() < 4:
             pytest.skip("needs 4 devices")
-        mesh = jax.make_mesh((2, 2, 1), ("pod", "data", "model"))
+        mesh = auto_mesh((2, 2, 1), ("pod", "data", "model"))
         # 8 % (2*2) == 0: both axes; 2 % 4 != 0 but 2 % 2 == 0: 'pod' only
         assert logical_spec(TRAIN_RULES, ("batch",), (8,),
                             mesh) == P(("pod", "data"))

@@ -55,7 +55,12 @@ class TestChromaticEquivalence:
         assert np.abs(rk - rd).max() <= TOL
 
     def test_als(self):
-        g, _ = make_als_graph(30, 35, 260, d=4, seed=1)
+        # 1000 drawn ratings leave ~260 distinct training pairs for the 65
+        # four-dimensional factors.  With 260 draws there were 111: the
+        # problem is underdetermined, its sweeps drift along flat
+        # directions, and 40 of them grow the 1-ulp rounding difference
+        # between XLA's fusions of the two jitted steps past 1e-5.
+        g, _ = make_als_graph(30, 35, 1000, d=4, seed=1)
         prog = ALSProgram(d=4)
         dense = ChromaticEngine(prog, g, tolerance=1e-4, use_fused=False)
         fused = ChromaticEngine(prog, g, tolerance=1e-4, use_fused=True)
@@ -71,7 +76,7 @@ class TestChromaticEquivalence:
         dense = ChromaticEngine(prog, g, tolerance=1e-4, use_fused=False)
         fused = ChromaticEngine(prog, g, tolerance=1e-4, use_fused=True)
         # edge writes are non-fuseable: requesting fusion must fall back
-        assert not fused.use_fused and fused._color_edges is None
+        assert not fused.use_fused and fused._consts["gas"] is None
         bd, _ = _fixed_point(dense, g, "belief", max_steps=30)
         bf, _ = _fixed_point(fused, g, "belief", max_steps=30)
         assert np.abs(bf - bd).max() <= TOL

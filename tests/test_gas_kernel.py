@@ -117,24 +117,27 @@ class TestGatherCombine:
         np.testing.assert_array_equal(counts, expect)
 
     def test_structure_csr_blocks_covers_every_edge(self):
-        """GraphStructure.csr_blocks agrees with the EdgeSet metadata and
-        every edge's receiver row block covers its edge block."""
+        """The flattened grid over a structure's receivers visits, for
+        every edge, the (receiver row block, edge block) pair it lives in;
+        row blocks come in order and each has at least one step."""
         from repro.core.graph import GraphStructure
+        from repro.kernels.gas.gas import csr_steps
         rng = np.random.default_rng(4)
         snd, recv = _random_edges(rng, 400, 3000, True)
         st_, _ = GraphStructure.from_edges(snd, recv, 400)
-        start, n_eblk, max_eblk = st_.csr_blocks()
-        assert n_eblk.min() >= 1 and int(n_eblk.max()) == max_eblk
+        step_rb, step_eb = csr_steps(st_.receivers, st_.n_vertices)
+        assert (np.diff(step_rb) >= 0).all()
+        np.testing.assert_array_equal(np.unique(step_rb),
+                                      np.arange(-(-400 // ROW_BLOCK)))
+        pairs = set(zip(step_rb.tolist(), step_eb.tolist()))
         eblk_of_edge = np.arange(st_.n_edges) // EDGE_BLOCK
         rblk_of_edge = st_.receivers // ROW_BLOCK
-        assert (start[rblk_of_edge] <= eblk_of_edge).all()
-        assert (eblk_of_edge < start[rblk_of_edge]
-                + n_eblk[rblk_of_edge]).all()
+        assert set(zip(rblk_of_edge.tolist(), eblk_of_edge.tolist())) <= pairs
 
     def test_exact_edge_block_multiple_stays_in_range(self):
         """E an exact EDGE_BLOCK multiple with trailing empty row blocks:
-        block starts must stay inside the real block range (the compiled
-        kernel would read out of bounds otherwise)."""
+        every step's edge block must stay inside the real block range (the
+        compiled kernel would read out of bounds otherwise)."""
         n = 600
         e = EDGE_BLOCK  # all receivers < 128 → row blocks 1.. are empty
         rng = np.random.default_rng(5)
@@ -142,8 +145,8 @@ class TestGatherCombine:
         snd = rng.integers(0, n, e).astype(np.int32)
         edges = EdgeSet.build(snd, recv, n)
         nblocks = edges.senders.shape[0] // EDGE_BLOCK
-        start, n_eblk = np.asarray(edges.eblk_start), np.asarray(edges.n_eblk)
-        assert (start + n_eblk <= nblocks).all(), (start, n_eblk)
+        step_eb = np.asarray(edges.step_eb)
+        assert ((step_eb >= 0) & (step_eb < nblocks)).all(), step_eb
         feat = jnp.asarray(rng.normal(size=(n, 4)), jnp.float32)
         w = jnp.asarray(rng.normal(size=e), jnp.float32)
         out = np.asarray(gather_combine(feat, w, edges, interpret=True))
@@ -206,7 +209,7 @@ class TestNoEdgeDimIntermediates:
     @staticmethod
     def _als():
         from repro.apps.als import ALSProgram, make_als_graph
-        g, _ = make_als_graph(40, 45, 330, d=4, seed=5)
+        g, _ = make_als_graph(60, 70, 700, d=4, seed=5)
         return ALSProgram(d=4), g
 
     def test_fused_pagerank_step_has_no_edge_matrix(self):

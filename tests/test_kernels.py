@@ -59,6 +59,29 @@ class TestSegSum:
                                    rtol=1e-4, atol=1e-4)
 
 
+    def test_tpu_dispatch_takes_lane_multiples_only(self, monkeypatch):
+        """The width rule of segment_combine's TPU dispatch: a sorted sum
+        whose width is a multiple of 128 lanes goes to the kernel, a
+        narrower one (LBP's [E, K] messages) stays on XLA's segment_sum
+        instead of being padded to 128 lanes."""
+        from repro.core.graph import segment_combine
+        from repro.kernels.segsum import ops
+
+        calls = []
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        monkeypatch.setattr(ops, "segment_sum_sorted",
+                            lambda m, r, n: calls.append(m.shape[1]) or
+                            segment_sum_sorted_ref(m, jnp.asarray(r), n))
+        recv = np.asarray([0, 0, 1, 3], np.int32)
+        for width in (1, 2, 100, 128, 256):
+            msgs = jnp.ones((4, width), jnp.float32)
+            out = segment_combine(msgs, jnp.asarray(recv), 4,
+                                  receivers_np=recv)
+            np.testing.assert_array_equal(np.asarray(out)[:, 0],
+                                          [2, 1, 0, 1])
+        assert calls == [128, 256]
+
+
 class TestFlashAttention:
     @settings(max_examples=10, deadline=None)
     @given(b=st.integers(1, 3), s=st.integers(8, 400),
