@@ -190,7 +190,8 @@ def _one_case(name: str, rng: np.random.Generator) -> Dict:
     # zero host-harness remediation: every migrate/shed above came out of
     # supervisor.actions — the harness only injected chaos
     rec["host_remediation_calls"] = 0
-    remedy_kinds = {"migrate_leave", "migrate_join", "shed_atoms"}
+    remedy_kinds = {"graphlab.migrate_leave", "graphlab.migrate_join",
+                    "graphlab.shed_atoms"}
     rec["timeline_has_remedies"] = remedy_kinds <= {
         e["name"] for e in ses.timeline.events if e.get("ph") == "X"}
     if name == "pagerank":

@@ -20,7 +20,7 @@ def trace_run(engine, graph, label, max_steps=60):
     state, trace = engine.run(
         state, max_steps=max_steps,
         trace_fn=lambda s: {"test_rmse": als_rmse(s.graph, train=False)})
-    ups = [t["total_updates"] for t in trace]
+    ups = [t["updates"] for t in trace]
     rmse = [t["test_rmse"] for t in trace]
     print(f"{label:32s} updates={ups[-1]:7d} test RMSE={rmse[-1]:.4f} "
           f"(min {min(rmse):.4f})")

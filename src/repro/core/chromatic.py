@@ -35,6 +35,7 @@ from repro.core.update import VertexProgram
 from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
 from repro.kernels.gas.ops import (EdgeSet, color_runs, size_class,
                                    split_by_color, stack_edge_sets)
+from repro.obs.timeline import span
 
 
 class ChromaticEngine(Engine):
@@ -56,14 +57,16 @@ class ChromaticEngine(Engine):
         residual_dtype=None,
         spare_colors: int = 0,
     ):
-        if colors is None:
-            colors = coloring_for(graph.structure, program.consistency)
-        colors = np.asarray(colors, dtype=np.int32)
-        radius = program.consistency.exclusion_radius
-        if radius >= 1 and not verify_coloring(graph.structure, colors, radius):
-            raise ValueError(
-                f"coloring does not satisfy {program.consistency} "
-                f"(radius {radius})")
+        with span("graphlab.coloring"):
+            if colors is None:
+                colors = coloring_for(graph.structure, program.consistency)
+            colors = np.asarray(colors, dtype=np.int32)
+            radius = program.consistency.exclusion_radius
+            if radius >= 1 and not verify_coloring(graph.structure, colors,
+                                                   radius):
+                raise ValueError(
+                    f"coloring does not satisfy {program.consistency} "
+                    f"(radius {radius})")
         super().__init__(
             program, graph, tolerance, sync_ops,
             scheduler=SweepScheduler(program, graph.structure, tolerance,

@@ -33,6 +33,7 @@ from repro.core.update import (EdgeCtx, VertexProgram, edge_ctx,
 from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
 from repro.kernels.gas.ops import (EdgeSet, ScatterCtx, active_row_blocks,
                                    gather_combine, stack_edge_sets)
+from repro.obs.timeline import span
 
 Pytree = Any
 
@@ -112,13 +113,15 @@ def apply_phase(
     receivers = jnp.asarray(st.receivers)
     senders = jnp.asarray(st.senders)
 
-    ctx = edge_ctx(graph)
-    msgs = program.gather(ctx)
-    acc = segment_combine(msgs, receivers, st.n_vertices, program.combiner,
-                          receivers_np=st.receivers)
+    with jax.named_scope("graphlab.gather"):
+        ctx = edge_ctx(graph)
+        msgs = program.gather(ctx)
+        acc = segment_combine(msgs, receivers, st.n_vertices,
+                              program.combiner, receivers_np=st.receivers)
 
-    new_v, residual = program.apply(graph.vertex_data, acc, glob)
-    vdata = masked_update(graph.vertex_data, new_v, mask)
+    with jax.named_scope("graphlab.apply"):
+        new_v, residual = program.apply(graph.vertex_data, acc, glob)
+        vdata = masked_update(graph.vertex_data, new_v, mask)
     graph = graph.replace(vertex_data=vdata)
 
     if program.has_edge_out:
@@ -132,7 +135,8 @@ def apply_phase(
         edata = masked_update(graph.edge_data, new_e, mask[senders])
         graph = graph.replace(edge_data=edata)
 
-    residual = jnp.where(mask, residual.astype(residual_dtype), 0.0)
+    with jax.named_scope("graphlab.apply"):
+        residual = jnp.where(mask, residual.astype(residual_dtype), 0.0)
     return graph, residual, jnp.asarray(st.n_edges, jnp.int32)
 
 
@@ -158,7 +162,8 @@ def fused_apply_phase(
     """
     st = graph.structure
     leaves, treedef = fused_gather_leaves(program)
-    block_active = active_row_blocks(mask)
+    with jax.named_scope("graphlab.gather"):
+        block_active = active_row_blocks(mask)
     # out-degree of each full-edge source — only degree_normalized_src
     # leaves consult it, so don't gather/ship an [E] array otherwise
     src_deg = jnp.asarray(st.out_degree[st.senders]) if any(
@@ -166,23 +171,27 @@ def fused_apply_phase(
 
     acc_leaves = []
     for leaf in leaves:
-        feat = leaf.feature(graph.vertex_data)
+        with jax.named_scope("graphlab.gather"):
+            feat = leaf.feature(graph.vertex_data)
         trailing = feat.shape[1:]
         feat2 = feat.reshape(st.n_vertices, -1)
-        w = fused_edge_weight(leaf, graph.edge_data, st.n_edges, src_deg)
-        if edges.perm is not None:
-            w = w[edges.perm]
+        with jax.named_scope("graphlab.edge_weight"):
+            w = fused_edge_weight(leaf, graph.edge_data, st.n_edges, src_deg)
+            if edges.perm is not None:
+                w = w[edges.perm]
         acc = gather_combine(feat2, w, edges, block_active=block_active,
                              interpret=interpret)
         acc_leaves.append(acc.reshape((st.n_vertices,) + trailing))
     acc = jax.tree.unflatten(treedef, acc_leaves)
 
-    new_v, residual = program.apply(graph.vertex_data, acc, glob)
-    vdata = masked_update(graph.vertex_data, new_v, mask)
+    with jax.named_scope("graphlab.apply"):
+        new_v, residual = program.apply(graph.vertex_data, acc, glob)
+        vdata = masked_update(graph.vertex_data, new_v, mask)
+        residual = jnp.where(mask, residual.astype(residual_dtype), 0.0)
     graph = graph.replace(vertex_data=vdata)
-    residual = jnp.where(mask, residual.astype(residual_dtype), 0.0)
-    edges_touched = jnp.sum(
-        jnp.where(block_active > 0, edges.block_counts, 0)).astype(jnp.int32)
+    with jax.named_scope("graphlab.gather"):
+        edges_touched = jnp.sum(jnp.where(
+            block_active > 0, edges.block_counts, 0)).astype(jnp.int32)
     return graph, residual, edges_touched
 
 
@@ -228,7 +237,8 @@ def stream_apply_phase(
 
     if fused_meta is not None:
         leaves, treedef, step_rb, step_eb, e_pad = fused_meta
-        block_active = active_row_blocks(mask)
+        with jax.named_scope("graphlab.gather"):
+            block_active = active_row_blocks(mask)
         snd = jnp.pad(senders, (0, e_pad - e_cap))
         rcv = jnp.pad(receivers, (0, e_pad - e_cap),
                       constant_values=n + ROW_BLOCK)
@@ -238,18 +248,22 @@ def stream_apply_phase(
             leaf.kind == "degree_normalized_src" for leaf in leaves) else None
         acc_leaves = []
         for leaf in leaves:
-            feat = leaf.feature(graph.vertex_data)
+            with jax.named_scope("graphlab.gather"):
+                feat = leaf.feature(graph.vertex_data)
             trailing = feat.shape[1:]
-            w = fused_edge_weight(leaf, graph.edge_data, e_cap, src_deg_e)
-            w = jnp.where(emask, w, 0.0)
+            with jax.named_scope("graphlab.edge_weight"):
+                w = fused_edge_weight(leaf, graph.edge_data, e_cap,
+                                      src_deg_e)
+                w = jnp.where(emask, w, 0.0)
             acc = gather_combine(feat.reshape(n, -1), w, es,
                                  block_active=block_active,
                                  interpret=interpret)
             acc_leaves.append(acc.reshape((n,) + trailing))
         acc = jax.tree.unflatten(treedef, acc_leaves)
-        edges_touched = jnp.sum(
-            jnp.where(block_active > 0, tables["block_counts"], 0)
-        ).astype(jnp.int32)
+        with jax.named_scope("graphlab.gather"):
+            edges_touched = jnp.sum(
+                jnp.where(block_active > 0, tables["block_counts"], 0)
+            ).astype(jnp.int32)
     else:
         rp = jnp.maximum(tables["rev_idx"], 0)
         has_rev = tables["rev_idx"] >= 0
@@ -259,22 +273,24 @@ def stream_apply_phase(
             m = has_rev.reshape((-1,) + (1,) * (y.ndim - 1))
             return jnp.where(m, y, jnp.zeros_like(y))
 
-        ctx = EdgeCtx(
-            edata=graph.edge_data,
-            rev_edata=jax.tree.map(_rev, graph.edge_data),
-            src=jax.tree.map(lambda x: x[senders], graph.vertex_data),
-            dst=jax.tree.map(lambda x: x[receivers], graph.vertex_data),
-            src_deg=tables["out_deg"][senders],
-            dst_deg=tables["in_deg"][receivers])
-        msgs = program.gather(ctx)
-        recv_idx = jnp.where(emask, receivers, n)
-        acc = segment_combine(msgs, recv_idx, n + 1, program.combiner,
-                              indices_are_sorted=False)
-        acc = jax.tree.map(lambda a: a[:n], acc)
-        edges_touched = jnp.sum(emask.astype(jnp.int32))
+        with jax.named_scope("graphlab.gather"):
+            ctx = EdgeCtx(
+                edata=graph.edge_data,
+                rev_edata=jax.tree.map(_rev, graph.edge_data),
+                src=jax.tree.map(lambda x: x[senders], graph.vertex_data),
+                dst=jax.tree.map(lambda x: x[receivers], graph.vertex_data),
+                src_deg=tables["out_deg"][senders],
+                dst_deg=tables["in_deg"][receivers])
+            msgs = program.gather(ctx)
+            recv_idx = jnp.where(emask, receivers, n)
+            acc = segment_combine(msgs, recv_idx, n + 1, program.combiner,
+                                  indices_are_sorted=False)
+            acc = jax.tree.map(lambda a: a[:n], acc)
+            edges_touched = jnp.sum(emask.astype(jnp.int32))
 
-    new_v, residual = program.apply(graph.vertex_data, acc, glob)
-    vdata = masked_update(graph.vertex_data, new_v, mask)
+    with jax.named_scope("graphlab.apply"):
+        new_v, residual = program.apply(graph.vertex_data, acc, glob)
+        vdata = masked_update(graph.vertex_data, new_v, mask)
     graph = graph.replace(vertex_data=vdata)
 
     prio_bump = None
@@ -293,7 +309,8 @@ def stream_apply_phase(
         edata = masked_update(graph.edge_data, new_e, wmask)
         graph = graph.replace(edge_data=edata)
 
-    residual = jnp.where(mask, residual.astype(residual_dtype), 0.0)
+    with jax.named_scope("graphlab.apply"):
+        residual = jnp.where(mask, residual.astype(residual_dtype), 0.0)
     return graph, residual, edges_touched, prio_bump
 
 
@@ -404,7 +421,8 @@ class Engine:
         static = stream_tables is None
         self._phase_groups, gas = ([], None)
         if self.use_fused and static:
-            self._phase_groups, gas = self._phase_edge_sets()
+            with span("graphlab.edge_sets"):
+                self._phase_groups, gas = self._phase_edge_sets()
         self._consts = {
             "gas": gas,
             "colors": (self.scheduler.colors
@@ -510,8 +528,9 @@ class Engine:
 
         def run_phase(phase, sets, carry):
             graph, prio, sched, count, total, edges_t = carry
-            mask, sched = self.scheduler.select(sched, prio, phase,
-                                                tables=select_tables)
+            with jax.named_scope("graphlab.select"):
+                mask, sched = self.scheduler.select(sched, prio, phase,
+                                                    tables=select_tables)
             if tables is None:
                 graph, residual, et = apply_phase(
                     self.program, graph, mask, glob,
@@ -524,13 +543,18 @@ class Engine:
                     fused_meta=self._stream_fused_meta,
                     interpret=self.gas_interpret, tolerance=self.tolerance,
                     residual_dtype=self.residual_dtype)
-            prio, sched = self.scheduler.reschedule(
-                sched, prio, mask, residual, tables=tables,
-                scatter=self._scatter_ctx(tables, sets))
-            if tables is not None and bump is not None:
-                prio = prio + bump
-            return (graph, prio, sched, count + mask.astype(jnp.int32),
-                    total + jnp.sum(mask.astype(jnp.int32)), edges_t + et)
+            with jax.named_scope("graphlab.reschedule"):
+                prio, sched = self.scheduler.reschedule(
+                    sched, prio, mask, residual, tables=tables,
+                    scatter=self._scatter_ctx(tables, sets))
+                if tables is not None and bump is not None:
+                    prio = prio + bump
+            with jax.named_scope("graphlab.apply"):
+                count = count + mask.astype(jnp.int32)
+                total = total + jnp.sum(mask.astype(jnp.int32))
+            with jax.named_scope("graphlab.gather"):
+                edges_t = edges_t + et
+            return graph, prio, sched, count, total, edges_t
 
         carry = (state.graph, state.prio, state.sched, state.update_count,
                  state.total_updates, state.edges_touched)
@@ -549,8 +573,10 @@ class Engine:
 
                 def body(phase, c, first=first, stacked=stacked,
                          shared=shared):
-                    sets = jax.tree.map(
-                        lambda x: x[0 if shared else phase - first], stacked)
+                    with jax.named_scope("graphlab.edge_sets"):
+                        sets = jax.tree.map(
+                            lambda x: x[0 if shared else phase - first],
+                            stacked)
                     return run_phase(phase, sets, c)
 
                 if n == 1:
@@ -567,11 +593,13 @@ class Engine:
 
     # -- shared driver --------------------------------------------------------
     def init(self, graph: DataGraph, initial_prio=None) -> EngineState:
-        state = init_state(self.program, graph, initial_prio, self.sync_ops,
-                           scheduler=self.scheduler)
-        if self.residual_dtype != jnp.float32:
-            state = state.replace(prio=state.prio.astype(self.residual_dtype))
-        return state
+        with span("graphlab.upload"):
+            state = init_state(self.program, graph, initial_prio,
+                               self.sync_ops, scheduler=self.scheduler)
+            if self.residual_dtype != jnp.float32:
+                state = state.replace(
+                    prio=state.prio.astype(self.residual_dtype))
+            return jax.block_until_ready(state)
 
     def step(self, state: EngineState) -> EngineState:
         return self._jit_step(state, self._tables, self._consts)
@@ -580,15 +608,18 @@ class Engine:
         """Compiles the step ahead of time for ``state``'s shapes; ``step``
         and ``run`` then call that executable.  Returns it, for its
         ``as_text()`` and ``memory_analysis()``."""
-        self._jit_step = self._jit_step.lower(
-            state, self._tables, self._consts).compile()
+        with span("graphlab.lower"):
+            lowered = self._jit_step.lower(state, self._tables, self._consts)
+        with span("graphlab.compile"):
+            self._jit_step = lowered.compile()
         return self._jit_step
 
     def _run_syncs(self, state: EngineState, prev_vdata) -> EngineState:
         if not self.sync_ops:
             return state
-        g = run_syncs(self.sync_ops, state.graph.vertex_data, prev_vdata,
-                      self.structure.n_vertices)
+        with jax.named_scope("graphlab.sync"):
+            g = run_syncs(self.sync_ops, state.graph.vertex_data, prev_vdata,
+                          self.structure.n_vertices)
         return state.replace(globals_=g)
 
     def run(
@@ -610,8 +641,7 @@ class Engine:
 
         Trace rows follow the canonical schema (obs.metrics.METRICS_SCHEMA
         — ``step``/``updates``/``edges_touched``/``residual_max``/
-        ``backlog`` plus structurally-zero traffic fields), with the old
-        ``total_updates`` key kept as a deprecated alias; ``trace_fn``
+        ``backlog`` plus structurally-zero traffic fields); ``trace_fn``
         extras are merged on top.  Rows are recorded lazily as device
         scalars and fetched with **one** host transfer every
         ``trace_every`` steps (default: ``obs.trace_every``, i.e. 1 — the
@@ -620,33 +650,36 @@ class Engine:
         every step — for a ``WorkStealingScheduler`` it fires
         ``steal_backlog`` when per-queue update counters skew; a
         ``session`` (obs.ObsSession) additionally receives rows, events,
-        and timeline spans.
+        and timeline spans.  Host spans (``obs.span``): ``graphlab.run``
+        over the call, and per step ``graphlab.done`` (the scheduler's
+        check, which blocks on the device) and ``graphlab.dispatch``
+        (``step``).
         """
         from repro.obs.metrics import RowCollector, lazy_local_row
         every = int(trace_every) if trace_every is not None \
             else self.obs.trace_every
         want_rows = (trace_fn is not None or self.obs.enabled
                      or session is not None)
-        col = RowCollector(every, session=session,
-                           legacy=self.obs.legacy_aliases)
-        tl = session.timeline if session is not None else None
-        for _ in range(max_steps):
-            if bool(self.scheduler.done(state.sched, state.prio)):
-                break
-            t0 = tl.now() if tl is not None else 0.0
-            state = self.step(state)
-            if supervisor is not None:
-                _, state = supervisor.observe(self, state)
-            if tl is not None:
-                tl.span("step", t0, tl.now(), track="local", cat="step")
-            if want_rows:
-                row = lazy_local_row(state, self.tolerance,
-                                     self.obs.residual_quantiles)
-                row["backlog"] = self.scheduler.backlog(state.sched,
-                                                        state.prio)
-                col.push(row,
-                         extra=dict(trace_fn(state)) if trace_fn else None)
-        col.drain()
+        col = RowCollector(every, session=session)
+        with span("graphlab.run", session=session, track="local"):
+            for _ in range(max_steps):
+                with span("graphlab.done", session=session, track="local"):
+                    done = bool(self.scheduler.done(state.sched, state.prio))
+                if done:
+                    break
+                with span("graphlab.dispatch", session=session,
+                          track="local"):
+                    state = self.step(state)
+                if supervisor is not None:
+                    _, state = supervisor.observe(self, state)
+                if want_rows:
+                    row = lazy_local_row(state, self.tolerance,
+                                         self.obs.residual_quantiles)
+                    row["backlog"] = self.scheduler.backlog(state.sched,
+                                                            state.prio)
+                    col.push(row, extra=dict(trace_fn(state))
+                             if trace_fn else None)
+            col.drain()
         return state, col.rows
 
     def run_while(self, state: EngineState, max_steps: int = 100) -> EngineState:
@@ -657,9 +690,10 @@ class Engine:
         stay retrace-free; they thread the tables as arguments)."""
 
         def cond(s):
-            return jnp.logical_and(
-                s.step_index < max_steps,
-                jnp.logical_not(self.scheduler.done(s.sched, s.prio)))
+            with jax.named_scope("graphlab.done"):
+                return jnp.logical_and(
+                    s.step_index < max_steps,
+                    jnp.logical_not(self.scheduler.done(s.sched, s.prio)))
 
         return jax.lax.while_loop(
             cond, lambda s: self._step(s, self._tables, self._consts),

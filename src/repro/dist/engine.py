@@ -67,6 +67,7 @@ from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
 from repro.kernels.gas.ops import (EdgeSet, active_row_blocks, color_runs,
                                    gather_combine, scatter_reschedule,
                                    size_class, split_by_color)
+from repro.obs.timeline import span
 
 Pytree = Any
 
@@ -1188,7 +1189,10 @@ class ShardEngineBase:
         """Compiles the step ahead of time for ``state``'s shapes; ``step``
         and ``run`` then call that executable.  Returns it, for its
         ``as_text()`` and ``memory_analysis()``."""
-        self._jit_step = self._jit_step.lower(state, self._tables).compile()
+        with span("graphlab.lower"):
+            lowered = self._jit_step.lower(state, self._tables)
+        with span("graphlab.compile"):
+            self._jit_step = lowered.compile()
         return self._jit_step
 
     def run(self, state: DistState, max_steps: int = 100, *,
@@ -1198,9 +1202,7 @@ class ShardEngineBase:
         """Host driver loop.  Trace rows follow the canonical telemetry
         schema (obs.metrics.METRICS_SCHEMA): ``step``/``updates``/
         ``residual_max``/``backlog``/``wire_backlog``/
-        ``traffic_{rows,bytes}_{v,e,r}``; the pre-§3.15 keys
-        (``ghost_rows``, ``edge_bytes``, ``rank_rows``, ...) remain as
-        deprecated aliases for one release.  Rows are lazy device
+        ``traffic_{rows,bytes}_{v,e,r}``.  Rows are lazy device
         scalars, fetched with one host transfer per ``trace_every``
         steps (default ``obs.trace_every``); the per-step sync that
         remains is the NaN-safe termination check, which the control
@@ -1212,46 +1214,45 @@ class ShardEngineBase:
         ``supervisor.engine``, and the loop keeps stepping a converged
         state while ``supervisor.pending_work()`` (e.g. an offered
         machine still to join).  A ``session`` (obs.ObsSession) receives
-        rows, supervisor events, and step/marker-wave timeline spans.
+        rows, supervisor events, and timeline spans.  Host spans
+        (``obs.span``): ``graphlab.run`` over the call, and per step
+        ``graphlab.done`` (the termination check, which blocks on the
+        device) and ``graphlab.dispatch`` (``step``; its args say whether
+        a marker wave rode the step).
         """
         from repro.obs.metrics import RowCollector, lazy_dist_row
-        from repro.obs.timeline import step_spans
         eng = self
         every = int(trace_every) if trace_every is not None \
             else self.obs.trace_every
-        col = RowCollector(every, session=session,
-                           legacy=self.obs.legacy_aliases)
-        tl = session.timeline if session is not None else None
+        col = RowCollector(every, session=session)
         quant = self.obs.residual_quantiles if self.obs.enabled else None
-        steps_done = 0
-        for _ in range(max_steps):
-            # under a quantized wire, converged priorities are not enough:
-            # deferred/top-k deltas still owed to remote caches (the wire
-            # backlog) must drain first — deferral is never a drop.
-            # NaN residuals — a dead machine's poisoned shard — must hold
-            # the loop open for the supervisor to heal, and XLA's
-            # reduce_max does NOT reliably propagate NaN, so map them to
-            # +inf before reducing
-            if (float(jnp.max(jnp.where(jnp.isnan(state.prio), jnp.inf,
-                                        state.prio))) <= eng.tolerance
-                    and eng._wire_backlog(state) == 0
-                    and (supervisor is None
-                         or not supervisor.pending_work())):
-                break
-            waving = state.snap is not None
-            t0 = tl.now() if tl is not None else 0.0
-            state = eng.step(state)
-            if supervisor is not None:
-                eng, state = supervisor.observe(eng, state)
-            if tl is not None:
-                step_spans(tl, t0, tl.now(), steps_done,
-                           colors=getattr(eng, "num_colors", 0),
-                           overlap=eng.overlap, marker_wave=waving,
-                           engine=type(eng).__name__)
-            col.push(lazy_dist_row(state, eng.tolerance, quant,
-                                   beats=eng.obs.enabled))
-            steps_done += 1
-        col.drain()
+        track = type(self).__name__
+        with span("graphlab.run", session=session, track=track):
+            for _ in range(max_steps):
+                # under a quantized wire, converged priorities are not
+                # enough: deferred/top-k deltas still owed to remote caches
+                # (the wire backlog) must drain first — deferral is never
+                # a drop.  NaN residuals — a dead machine's poisoned shard
+                # — must hold the loop open for the supervisor to heal, and
+                # XLA's reduce_max does NOT reliably propagate NaN, so map
+                # them to +inf before reducing
+                with span("graphlab.done", session=session, track=track):
+                    done = (float(jnp.max(jnp.where(
+                        jnp.isnan(state.prio), jnp.inf, state.prio)))
+                        <= eng.tolerance
+                        and eng._wire_backlog(state) == 0
+                        and (supervisor is None
+                             or not supervisor.pending_work()))
+                if done:
+                    break
+                with span("graphlab.dispatch", session=session, track=track,
+                          args={"marker_wave": state.snap is not None}):
+                    state = eng.step(state)
+                if supervisor is not None:
+                    eng, state = supervisor.observe(eng, state)
+                col.push(lazy_dist_row(state, eng.tolerance, quant,
+                                       beats=eng.obs.enabled))
+            col.drain()
         return state, col.rows
 
     def _wire_backlog(self, state: DistState) -> int:

@@ -58,7 +58,6 @@ from repro.checkpoint.manager import (CheckpointManager, flatten_with_paths,
 from repro.core.graph import DataGraph
 from repro.core.scheduler import marker_wave_local
 from repro.core.snapshot import SnapshotState, capture_rows, stitch_rows
-from repro.obs.metrics import apply_aliases
 
 Pytree = Any
 
@@ -399,8 +398,7 @@ class DistSnapshotDriver:
                         "graph connected?")
                 prev_done = now_done
             # canonical telemetry keys (obs.metrics.METRICS_SCHEMA) plus
-            # the driver's snapshot-progress extras; ``max_prio`` stays as
-            # a deprecated alias of ``residual_max`` for one release
+            # the driver's snapshot-progress extras
             rec = {
                 "step": int(state.step_index),
                 "updates": int(np.asarray(state.update_count).sum()),
@@ -408,8 +406,6 @@ class DistSnapshotDriver:
                 "marker_rows": eng.marker_rows_sent(state),
                 "snapshot_done_frac": eng.snapshot_done_frac(state),
             }
-            if eng.obs.legacy_aliases:
-                apply_aliases(rec)
             trace.append(rec)
             if snapping and eng.snapshot_complete(state):
                 if self.manager is not None:

@@ -165,22 +165,24 @@ def gather_combine(
     """
     assert feat.ndim == 2, feat.shape
     e_pad = edges.senders.shape[0]
-    w = weights.astype(jnp.float32)
-    if w.shape[0] != e_pad:
-        w = jnp.pad(w, (0, e_pad - w.shape[0]))
-    if block_active is None:
-        block_active = jnp.ones((edges.n_row_blocks,), jnp.int32)
+    with jax.named_scope("graphlab.edge_weight"):
+        w = weights.astype(jnp.float32)
+        if w.shape[0] != e_pad:
+            w = jnp.pad(w, (0, e_pad - w.shape[0]))
 
-    if (not interpret and jax.default_backend() != "tpu") or \
-            not kernel_takes_gather(feat.shape[0], feat.shape[1],
-                                    edges.n_vertices):
-        return gather_combine_ref(
+    with jax.named_scope("graphlab.gather"):
+        if block_active is None:
+            block_active = jnp.ones((edges.n_row_blocks,), jnp.int32)
+        if (not interpret and jax.default_backend() != "tpu") or \
+                not kernel_takes_gather(feat.shape[0], feat.shape[1],
+                                        edges.n_vertices):
+            return gather_combine_ref(
+                feat, w, edges.senders, edges.receivers, edges.n_vertices,
+                block_active)
+        return gas_gather_combine_pallas(
             feat, w, edges.senders, edges.receivers, edges.n_vertices,
-            block_active)
-    return gas_gather_combine_pallas(
-        feat, w, edges.senders, edges.receivers, edges.n_vertices,
-        edges.step_rb, edges.step_eb, block_active,
-        interpret=bool(interpret))
+            edges.step_rb, edges.step_eb, block_active,
+            interpret=bool(interpret))
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -226,15 +228,17 @@ def scatter_reschedule(
             not edge_major_fits(contrib.shape[0], edges.n_vertices, True):
         # off the TPU, and for tables too large to stay in VMEM, the
         # scatter is the jnp oracle (the XLA segment_sum)
-        return scatter_reschedule_ref(
-            contrib, prio, consume, w, edges.senders, edges.receivers,
-            edges.n_vertices)
+        with jax.named_scope("graphlab.scatter"):
+            return scatter_reschedule_ref(
+                contrib, prio, consume, w, edges.senders, edges.receivers,
+                edges.n_vertices)
     # edge-block activity: a block matters only if some edge in it has a
     # contributing source and nonzero weight — bool work, invisible to the
     # float-intermediate accounting the kernel path is measured by
     live = jnp.logical_and(contrib[edges.senders] != 0.0, w != 0.0)
     eblk_active = live.reshape(-1, EDGE_BLOCK).any(axis=1)
-    return gas_scatter_reschedule_pallas(
-        contrib, prio, consume, w, edges.senders, edges.receivers,
-        edges.n_vertices, eblk_active,
-        interpret=bool(interpret))
+    with jax.named_scope("graphlab.scatter"):
+        return gas_scatter_reschedule_pallas(
+            contrib, prio, consume, w, edges.senders, edges.receivers,
+            edges.n_vertices, eblk_active,
+            interpret=bool(interpret))

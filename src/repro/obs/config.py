@@ -23,8 +23,7 @@ class ObsConfig:
     enabled
         Master switch.  Off (the default) reproduces the pre-telemetry
         trace behavior exactly: ``run`` returns rows only when asked
-        (``trace_fn`` locally; always for the dist engines), with the
-        legacy keys still present via aliases.
+        (``trace_fn`` locally; always for the dist engines).
     trace_every
         Batch size of the host drain: lazy per-step rows accumulate as
         device scalars and are converted with **one** ``device_get``
@@ -32,25 +31,20 @@ class ObsConfig:
         still recorded for *every* step — only the host transfer is
         batched.  1 (default) matches the old per-step behavior.
     timeline
-        Record host-side spans (step, per-color phase, ghost exchange,
-        marker waves, migrations, steals, ``apply_delta``/regrow) into
-        an ``obs.Timeline`` for Chrome-trace/Perfetto export.
+        Record the host spans (``graphlab.run``/``done``/``dispatch``,
+        migrations, steals, ``apply_delta``/regrow) into an
+        ``obs.Timeline`` for Chrome-trace/Perfetto export; the spans
+        reach the profiler's trace either way.
     residual_quantiles
         Extra residual quantiles (e.g. ``(0.5, 0.9)``) appended to each
         row as ``residual_q50``/``residual_q90``; None records only
         ``residual_max``.  Computed lazily outside the jitted step.
-    legacy_aliases
-        Emit the pre-§3.15 trace keys (``ghost_rows``, ``edge_bytes``,
-        ``total_updates``, ``max_prio``, ...) alongside the canonical
-        schema.  Deprecated — kept for one release; see
-        ``obs.metrics.LEGACY_ALIASES``.
     """
 
     enabled: bool = False
     trace_every: int = 1
     timeline: bool = False
     residual_quantiles: Optional[Tuple[float, ...]] = None
-    legacy_aliases: bool = True
 
     def __post_init__(self):
         if int(self.trace_every) < 1:

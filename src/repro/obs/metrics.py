@@ -11,13 +11,6 @@ unevaluated device scalars into a ``RowCollector``, and one
 Collection never adds an op to the jitted step — every field derives
 from counters already riding the state.
 
-The old keys remain available as aliases (``LEGACY_ALIASES``) for one
-release.  **Deprecated**: ``ghost_rows``→``traffic_rows_v``,
-``ghost_bytes``→``traffic_bytes_v``, ``edge_rows``→``traffic_rows_e``,
-``edge_bytes``→``traffic_bytes_e``, ``rank_rows``→``traffic_rows_r``,
-``rank_bytes``→``traffic_bytes_r``, ``total_updates``→``updates``,
-``max_prio``→``residual_max``.
-
 Snapshot-aligned aggregation (the paper's §4.3 move turned on the
 metrics themselves): a live per-step reduction over a distributed mesh
 mixes rows from different logical times — machine A's row may already
@@ -58,21 +51,6 @@ METRICS_SCHEMA: Dict[str, Tuple[str, str]] = {
     "beats": ("ti", "per-machine heartbeat counters (dist only)"),
 }
 
-#: canonical -> legacy key, emitted alongside while ``legacy_aliases`` is
-#: on (default).  Deprecated: readers should migrate to the canonical
-#: names; the aliases go away next release.
-LEGACY_ALIASES: Dict[str, str] = {
-    "updates": "total_updates",
-    "residual_max": "max_prio",
-    "traffic_rows_v": "ghost_rows",
-    "traffic_bytes_v": "ghost_bytes",
-    "traffic_rows_e": "edge_rows",
-    "traffic_bytes_e": "edge_bytes",
-    "traffic_rows_r": "rank_rows",
-    "traffic_bytes_r": "rank_bytes",
-}
-
-
 @dataclasses.dataclass
 class MetricsFrame:
     """One step's metrics under the canonical schema; unknown row keys
@@ -96,29 +74,17 @@ class MetricsFrame:
     @classmethod
     def from_row(cls, row: Dict[str, Any]) -> "MetricsFrame":
         known = {f.name for f in dataclasses.fields(cls)} - {"extra"}
-        legacy = set(LEGACY_ALIASES.values())
         kw = {k: v for k, v in row.items() if k in known}
-        kw["extra"] = {k: v for k, v in row.items()
-                       if k not in known and k not in legacy}
+        kw["extra"] = {k: v for k, v in row.items() if k not in known}
         return cls(**kw)
 
-    def to_row(self, legacy: bool = True) -> Dict[str, Any]:
+    def to_row(self) -> Dict[str, Any]:
         row = {f.name: getattr(self, f.name)
                for f in dataclasses.fields(self) if f.name != "extra"}
         if row["beats"] is None:
             del row["beats"]
         row.update(self.extra)
-        if legacy:
-            apply_aliases(row)
         return row
-
-
-def apply_aliases(row: Dict[str, Any]) -> Dict[str, Any]:
-    """Adds the deprecated legacy keys in place (canonical keys win)."""
-    for canon, old in LEGACY_ALIASES.items():
-        if canon in row and old not in row:
-            row[old] = row[canon]
-    return row
 
 
 # -- lazy rows + batched draining --------------------------------------------
@@ -138,10 +104,9 @@ class RowCollector:
     ``jax.device_get`` per drain, so telemetry adds no per-step sync.
     ``drains`` counts the transfers (asserted by tests)."""
 
-    def __init__(self, every: int = 1, session=None, legacy: bool = True):
+    def __init__(self, every: int = 1, session=None):
         self.every = max(1, int(every))
         self.session = session
-        self.legacy = legacy
         self.rows: List[Dict[str, Any]] = []
         self.drains = 0
         self._pending: List[Tuple[Dict[str, Any], Optional[Dict]]] = []
@@ -166,8 +131,6 @@ class RowCollector:
             if extra:
                 row.update({k: _py(v) for k, v in extra.items()})
             row.setdefault("step", None)
-            if self.legacy:
-                apply_aliases(row)
             batch.append(row)
         self.rows.extend(batch)
         if self.session is not None:

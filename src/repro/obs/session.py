@@ -7,12 +7,11 @@ It is deliberately dumb: no I/O, no device access; exporters
 (``obs/export.py``) serialize it after the run."""
 from __future__ import annotations
 
-from contextlib import nullcontext
 from typing import Any, Dict, List, Optional
 
 from repro.obs.config import ObsConfig
 from repro.obs.metrics import MetricsFrame
-from repro.obs.timeline import Timeline
+from repro.obs.timeline import Timeline, span
 
 
 class ObsSession:
@@ -45,11 +44,9 @@ class ObsSession:
         return ev
 
     def span(self, name: str, **kw):
-        """Timeline span context manager; a no-op when tracing is off —
-        instrumentation sites never need to branch."""
-        if self.timeline is None:
-            return nullcontext()
-        return self.timeline.spanning(name, **kw)
+        """``obs.span`` into this session: recorded into the timeline when
+        tracing is on — instrumentation sites never need to branch."""
+        return span(name, session=self, **kw)
 
 
 def attach_session(engine, session: Optional[ObsSession]) -> None:
@@ -65,9 +62,5 @@ def engine_session(engine) -> Optional[ObsSession]:
 
 
 def engine_span(engine, name: str, **kw):
-    """``session.span`` through an engine attachment; no-op context
-    manager when nothing is attached."""
-    ses = engine_session(engine)
-    if ses is None:
-        return nullcontext()
-    return ses.span(name, **kw)
+    """``obs.span`` into the engine's attached session, if any."""
+    return span(name, session=engine_session(engine), **kw)

@@ -39,6 +39,8 @@ from typing import Any, Dict, List, Optional
 import jax
 import numpy as np
 
+from repro.obs.timeline import span
+
 
 class Supervisor:
     """Consumes the metrics stream inside ``run()`` and fires
@@ -145,12 +147,11 @@ class Supervisor:
         self._shedded.clear()
         self._unremediated_dead.clear()
 
-    def _span(self, name: str, **kw):
-        from contextlib import nullcontext
-        if self.session is None:
-            return nullcontext()
-        return self.session.span(name, track="supervisor", cat="control",
-                                 **kw)
+    def _span(self, kind: str, **kw):
+        """``obs.span`` ``graphlab.<kind>`` of a remedy, on the
+        supervisor's timeline track."""
+        return span(f"graphlab.{kind}", session=self.session,
+                    track="supervisor", cat="control", **kw)
 
     # -- dispatch ----------------------------------------------------------
     def observe(self, engine, state):

@@ -1,28 +1,79 @@
-"""Host-side timeline tracing (DESIGN.md §3.15, layer 2).
+"""Host spans (DESIGN.md §3.15, layer 2): one primitive, ``span``.
 
-Spans are **host-observed** wall-clock intervals around the dispatch of
-jitted work — XLA executes asynchronously, so a ``step`` span measures
-the host loop's view (dispatch + whatever blocking readback the loop
-performs), not device occupancy.  That is the honest observable for a
-driver loop, and it is exactly what the Supervisor's remediation
-latency is measured against.  Sub-step structure the host cannot time
-directly (per-color phases inside one jitted step) is synthesized as
-equal slices of the measured step and flagged ``logical: True`` in the
-event args so a reader never mistakes it for a measurement.
+``span(name)`` opens a ``jax.profiler.TraceAnnotation``, so the span
+lands in the profiler's host plane on the device trace's clock; adds its
+count and seconds to a process-wide table (``span_totals``); and, given a
+session with a timeline, records it into that ``Timeline`` too.  Spans
+are **host-observed** intervals: XLA executes asynchronously, so a span
+around a dispatch measures the host's view, and a span around a blocking
+read (``graphlab.done``) holds the device's tail.  Where the device's
+time goes inside a step is the profiler's to say, under the step's
+``jax.named_scope`` names (``graphlab.select``/``edge_weight``/...).
+Every program span name starts with ``graphlab.``.
 
 Export (``obs/export.py``) emits the Chrome trace event format, which
-Perfetto and chrome://tracing both load.
+Perfetto and chrome://tracing both load, for runs without the profiler.
 """
 from __future__ import annotations
 
+import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
+
+PREFIX = "graphlab."
+
+
+class SpanTotal(NamedTuple):
+    count: int
+    seconds: float
+
+
+_totals: Dict[str, SpanTotal] = {}
+_totals_lock = threading.Lock()
+
+
+def span_totals() -> Dict[str, SpanTotal]:
+    """Count and host seconds of every span closed in this process since
+    the last ``reset_span_totals``, by name."""
+    with _totals_lock:
+        return dict(_totals)
+
+
+def reset_span_totals() -> None:
+    with _totals_lock:
+        _totals.clear()
+
+
+@contextmanager
+def span(name: str, *, session=None, track: str = "host", cat: str = "span",
+         args: Optional[Dict[str, Any]] = None):
+    """The span primitive: a profiler annotation, a ``span_totals`` entry
+    and, when ``session`` (an ``ObsSession``) has a timeline, a timeline
+    event on ``track``."""
+    if not name.startswith(PREFIX):
+        raise ValueError(f"span {name!r}: program spans start with {PREFIX!r}")
+    tl = getattr(session, "timeline", None)
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(name):
+            yield
+    finally:
+        t1 = time.perf_counter()
+        with _totals_lock:
+            c, s = _totals.get(name, (0, 0.0))
+            _totals[name] = SpanTotal(c + 1, s + (t1 - t0))
+        if tl is not None:
+            tl.span(name, t0 - tl._t0, t1 - tl._t0, track=track, cat=cat,
+                    args=args)
 
 
 class Timeline:
     """An append-only list of Chrome-trace events with a private epoch;
-    ``ts``/``dur`` are microseconds since construction."""
+    ``ts``/``dur`` are microseconds since construction.  Spans reach it
+    through ``span(..., session=)``."""
 
     def __init__(self):
         self.events: List[Dict[str, Any]] = []
@@ -49,15 +100,6 @@ class Timeline:
             "pid": 0, "tid": self._tid(track), "args": dict(args or {}),
         })
 
-    @contextmanager
-    def spanning(self, name: str, *, track: str = "host", cat: str = "step",
-                 args: Optional[Dict[str, Any]] = None):
-        t0 = self.now()
-        try:
-            yield
-        finally:
-            self.span(name, t0, self.now(), track=track, cat=cat, args=args)
-
     def instant(self, name: str, *, track: str = "events", cat: str = "event",
                 args: Optional[Dict[str, Any]] = None) -> None:
         self.events.append({
@@ -81,31 +123,3 @@ class Timeline:
                  "args": {"name": track}}
                 for track, tid in self._tracks.items()]
 
-
-def step_spans(tl: Timeline, t0: float, t1: float, step: int, *,
-               colors: int = 0, overlap: bool = False,
-               marker_wave: bool = False, engine: str = "dist") -> None:
-    """The per-step span family the engine run loops emit: the step
-    itself, an optional marker-wave child, and per-color phase slices
-    (``logical: True`` — synthesized, see module docstring) with the
-    ghost exchange of color c-1 marked in-flight during color c when
-    the double-buffered overlap is on."""
-    tl.span(f"step {step}", t0, t1, track=engine, cat="step",
-            args={"step": step})
-    if marker_wave:
-        tl.span("marker wave", t0, t1, track="snapshot", cat="snapshot",
-                args={"step": step, "logical": True})
-    if colors > 1:
-        w = (t1 - t0) / colors
-        for c in range(colors):
-            a, b = t0 + c * w, t0 + (c + 1) * w
-            tl.span(f"phase c{c}", a, b, track=f"{engine}/phases",
-                    cat="phase", args={"step": step, "color": c,
-                                       "logical": True})
-            if overlap and c > 0:
-                # color c-1's encoded packet is on the wire while color
-                # c computes — the §3.14 double-buffer
-                tl.span(f"ghost pkt c{c - 1} (in flight)", a, b,
-                        track=f"{engine}/wire", cat="exchange",
-                        args={"step": step, "color": c - 1,
-                              "deferred": True, "logical": True})

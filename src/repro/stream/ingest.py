@@ -1090,8 +1090,8 @@ def apply_delta(engine, state, batch: DeltaBatch, *, record: bool = True):
             _DistPatcher(engine) if isinstance(engine, ShardEngineBase)
             else _LocalPatcher(engine))
     from repro.obs.session import engine_span
-    with engine_span(engine, "apply_delta", track="stream", cat="delta",
-                     args={"commands": len(batch)}):
+    with engine_span(engine, "graphlab.apply_delta", track="stream",
+                     cat="delta", args={"commands": len(batch)}):
         new_state = engine._stream_patcher.apply(state, batch)
     journal = getattr(engine, "_stream_journal", None)
     if journal is not None and record:
@@ -1222,7 +1222,8 @@ def regrow_engine(engine, state, *, slack: Optional[SlackConfig] = None,
     Returns ``(engine, state)``; the old pair is dead.
     """
     from repro.obs.session import engine_span
-    with engine_span(engine, "regrow", track="stream", cat="delta"):
+    with engine_span(engine, "graphlab.regrow", track="stream",
+                     cat="delta"):
         return _regrow_engine(engine, state, slack=slack,
                               in_capacity=in_capacity, n_cap=n_cap)
 

@@ -265,7 +265,7 @@ class TestYoungIntervalDriver:
         # snapshot work never paused computation (Fig. 4 async property):
         # updates strictly accumulate every pre-convergence step, snapshot
         # in flight or not (post-convergence steps only drain the wave)
-        live = [t for t in trace if t["max_prio"] > 1e-10]
+        live = [t for t in trace if t["residual_max"] > 1e-10]
         assert len(live) >= 3
         assert all(b["updates"] > a["updates"]
                    for a, b in zip(live, live[1:]))

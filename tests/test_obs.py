@@ -1,8 +1,7 @@
 """Telemetry subsystem (repro/obs/; DESIGN §3.15).
 
 Covered here: (1) the unified trace schema — local and dist ``run``
-emit the same canonical keys, with the pre-§3.15 names kept as
-deprecated aliases; (2) batched host draining — rows are identical for
+emit the same canonical keys and no others; (2) batched host draining — rows are identical for
 any ``trace_every`` and the number of host transfers shrinks to
 ``ceil(steps / trace_every)``; (3) the zero-overhead off-switch — an
 engine built with telemetry enabled has a byte-identical step jaxpr to
@@ -10,7 +9,9 @@ one built without (collection never adds an op to the jitted step);
 (4) snapshot-aligned aggregation — the naive live reduction over a
 4-machine mesh mixes pre/post-cut rows while the marker-anchored
 aggregate equals a single-machine oracle restored from the same cut,
-bit-exactly; (5) Chrome-trace/JSONL export structure.
+bit-exactly; (5) Chrome-trace/JSONL export structure; (6) the span
+primitive — host spans in the profiler's trace and in ``span_totals``,
+and the ``graphlab.*`` device scopes in the compiled step.
 """
 import json
 import math
@@ -26,10 +27,11 @@ from repro.core.snapshot import restore_engine_state
 from repro.dist.engine import DistributedEngine
 from repro.dist.locking import DistributedLockingEngine
 from repro.graphs.generators import connected_power_law_graph
-from repro.obs import (LEGACY_ALIASES, METRICS_SCHEMA, MetricsFrame,
-                       ObsConfig, ObsSession, Supervisor, aligned_aggregate,
-                       chrome_trace, live_aggregate, mixing_report,
-                       write_chrome_trace, write_events_jsonl)
+from repro.obs import (METRICS_SCHEMA, MetricsFrame, ObsConfig, ObsSession,
+                       Supervisor, aligned_aggregate, chrome_trace,
+                       live_aggregate, mixing_report, reset_span_totals,
+                       span, span_totals, write_chrome_trace,
+                       write_events_jsonl)
 
 needs_mesh = pytest.mark.skipif(
     jax.device_count() < 4, reason="needs 4 forced host devices "
@@ -63,10 +65,8 @@ class TestUnifiedSchema:
                            trace_fn=lambda s: {"custom": 1.0})
         assert trace, "local run with trace_fn must emit rows"
         row = trace[0]
-        assert CANONICAL <= set(row)
-        # deprecated aliases mirror the canonical values (one release)
-        for canon, old in LEGACY_ALIASES.items():
-            assert row[old] == row[canon]
+        # the canonical keys and the trace_fn extra: no pre-§3.15 alias
+        assert set(row) == CANONICAL | {"custom"}
         assert row["custom"] == 1.0
         # local engines ship nothing: traffic fields structurally zero
         assert row["traffic_rows_v"] == row["traffic_bytes_v"] == 0
@@ -79,9 +79,7 @@ class TestUnifiedSchema:
         eng, state = _dist(cpu_mesh, tol=1e-6)
         _, trace = eng.run(state, max_steps=30)
         row = trace[0]
-        assert CANONICAL <= set(row)
-        for canon, old in LEGACY_ALIASES.items():
-            assert row[old] == row[canon]
+        assert set(row) == CANONICAL
         last = trace[-1]
         assert last["traffic_rows_v"] > 0
         # default f32 wire: bytes are rows x a fixed per-row payload size
@@ -97,8 +95,7 @@ class TestUnifiedSchema:
         assert f.updates == trace[0]["updates"]
         assert f.extra["custom"] == 2.5
         back = f.to_row()
-        assert back["updates"] == trace[0]["updates"]
-        assert back["total_updates"] == trace[0]["updates"]  # alias
+        assert back == trace[0]
 
 
 # ---------------------------------------------------------------------------
@@ -238,12 +235,19 @@ class TestTimelineExport:
         ses.event("unit_test_marker", detail=42)
 
         doc = chrome_trace(ses.timeline, metadata={"case": "pagerank"})
-        steps = [e for e in doc["traceEvents"]
-                 if e.get("ph") == "X" and e["name"].startswith("step")]
-        assert len(steps) == 5
-        assert all(e["dur"] >= 0 and e["ts"] >= 0 for e in steps)
-        phases = [e for e in doc["traceEvents"] if e.get("cat") == "phase"]
-        assert phases and all(e["args"]["logical"] for e in phases)
+        spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
+        by = lambda n: [e for e in spans if e["name"] == n]
+        steps, runs = by("graphlab.dispatch"), by("graphlab.run")
+        assert len(steps) == 5 and len(by("graphlab.done")) == 5
+        assert len(runs) == 1
+        assert all(e["dur"] >= 0 and e["ts"] >= 0 for e in spans)
+        run = runs[0]
+        assert all(run["ts"] <= e["ts"] and
+                   e["ts"] + e["dur"] <= run["ts"] + run["dur"]
+                   for e in steps)
+        # every span is measured: nothing is synthesized per color
+        assert all(e["name"].startswith("graphlab.") for e in spans)
+        assert not any("logical" in e["args"] for e in spans)
         names = [e for e in doc["traceEvents"] if e.get("ph") == "M"]
         assert names, "thread_name metadata labels the tracks"
 
@@ -264,3 +268,111 @@ class TestTimelineExport:
         assert ses.rows == trace
         assert len(ses.frames()) == len(trace)
         assert any(e["ph"] == "X" for e in ses.timeline.events)
+
+
+# ---------------------------------------------------------------------------
+# one span primitive: the profiler's trace, span_totals, the timeline
+# ---------------------------------------------------------------------------
+
+def _host_spans(trace_dir):
+    """(name, start_ns, end_ns) of the ``graphlab.*`` events in the
+    profiler's host planes."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = max(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                    for ev in line.events
+                    if ev.name.startswith("graphlab.")]
+    return out
+
+
+def _chromatic(n=60, **kw):
+    from repro.core import ChromaticEngine
+    g, prog, _ = _case(n=n, tol=1e-6)
+    return ChromaticEngine(prog, g, tolerance=1e-6, **kw), g
+
+
+class TestSpans:
+    def test_run_writes_nested_spans_into_the_profiler(self, tmp_path):
+        eng, g = _chromatic()
+        state = eng.init(g)
+        eng.run(state, max_steps=1)                  # compile outside
+        ses = ObsSession(ObsConfig(enabled=True, timeline=True))
+        with jax.profiler.trace(str(tmp_path)):
+            eng.run(state, max_steps=3)
+            with ses.span("graphlab.unit_test"):
+                pass
+        spans = _host_spans(str(tmp_path))
+        runs = [s for s in spans if s[0] == "graphlab.run"]
+        done = [s for s in spans if s[0] == "graphlab.done"]
+        dispatch = [s for s in spans if s[0] == "graphlab.dispatch"]
+        assert len(runs) == 1 and len(dispatch) == 3 and len(done) == 3
+        _, lo, hi = runs[0]
+        assert all(lo <= s <= e <= hi for _, s, e in done + dispatch)
+        # a session span lands in both records
+        assert [s for s in spans if s[0] == "graphlab.unit_test"]
+        assert [e for e in ses.timeline.events
+                if e["name"] == "graphlab.unit_test"]
+
+    def test_set_up_spans_counted_once_per_engine(self):
+        reset_span_totals()
+        for _ in range(2):
+            eng, g = _chromatic()
+            eng.compile(eng.init(g))
+        totals = span_totals()
+        for name in ("graphlab.coloring", "graphlab.edge_sets",
+                     "graphlab.upload", "graphlab.lower",
+                     "graphlab.compile"):
+            assert totals[name].count == 2, name
+            assert totals[name].seconds > 0, name
+        assert "graphlab.run" not in totals
+        reset_span_totals()
+        assert span_totals() == {}
+
+    def test_program_spans_are_named_graphlab(self):
+        with pytest.raises(ValueError, match="graphlab"):
+            with span("step"):
+                pass
+
+
+SCOPES = ("graphlab.select", "graphlab.edge_weight", "graphlab.gather",
+          "graphlab.apply", "graphlab.reschedule", "graphlab.scatter",
+          "graphlab.edge_sets")
+
+
+class TestDeviceScopes:
+    def test_compiled_step_carries_scopes(self):
+        """Every layer's scope survives into the compiled module's
+        ``op_name`` metadata, and instructions (fusions included) map to
+        the innermost ``graphlab.*`` component of theirs."""
+        import re
+        eng, g = _chromatic()
+        text = eng.compile(eng.init(g)).as_text()
+        scope_of = {}
+        for line in text.splitlines():
+            m = re.match(r'\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*'
+                         r'op_name="([^"]*)"', line)
+            if m:
+                inner = [p for p in m.group(2).split("/")
+                         if p.startswith("graphlab.")]
+                if inner:
+                    scope_of[m.group(1)] = inner[-1]
+        assert set(SCOPES) <= set(scope_of.values())
+        assert any("fusion" in name for name in scope_of)
+
+    def test_run_while_scopes_done_and_sync(self):
+        from repro.core.sync_op import FnSyncOp
+        total = FnSyncOp(map_fn=lambda v: {"s": v["rank"]},
+                         finalize=lambda z, n: z["s"], name="total")
+        eng, g = _chromatic(sync_ops=(total,))
+        text = jax.jit(lambda s: eng.run_while(s, 5)).lower(
+            eng.init(g)).as_text(debug_info=True)
+        assert "graphlab.done" in text and "graphlab.sync" in text

@@ -93,7 +93,8 @@ class TestDeathHealing:
         # a timeline span on the supervisor track
         assert any(e["kind"] == "migrate_leave" for e in ses.events)
         spans = [e for e in ses.timeline.events
-                 if e.get("ph") == "X" and e["name"] == "migrate_leave"]
+                 if e.get("ph") == "X"
+                 and e["name"] == "graphlab.migrate_leave"]
         assert spans and spans[0]["args"]["machine"] == 2
 
     def test_dead_without_manager_is_reported_not_hidden(self, cpu_mesh):
