@@ -55,8 +55,18 @@ class EngineState:
     edges_touched: jnp.ndarray  # scalar i64-ish — gathered-edge accounting
     globals_: Pytree           # sync-op outputs readable by update fns
     sched: Pytree = ()         # scheduler-private state (() if stateless)
+    # fused-gather edge weights prepared from ``graph.edge_data`` in each
+    # color-step's edge order (``Engine.prepare_weights``); () if none
+    gas_weights: Pytree = ()
 
     def replace(self, **kw) -> "EngineState":
+        """``dataclasses.replace``, except that a ``graph`` whose
+        ``edge_data`` is another object drops ``gas_weights`` (prepared
+        from the old edge data) unless the caller passes them too."""
+        graph = kw.get("graph")
+        if (graph is not None and "gas_weights" not in kw
+                and graph.edge_data is not self.graph.edge_data):
+            kw["gas_weights"] = ()
         return dataclasses.replace(self, **kw)
 
 
@@ -90,6 +100,7 @@ def apply_phase(
     glob: Pytree,
     *,
     edges: Optional[EdgeSet] = None,
+    weights: Optional[Sequence[jnp.ndarray]] = None,
     interpret: Optional[bool] = None,
     residual_dtype=jnp.float32,
 ) -> Tuple[DataGraph, jnp.ndarray, jnp.ndarray]:
@@ -99,7 +110,8 @@ def apply_phase(
     out-edges of updated vertices).  Returns (new graph, residual·mask,
     edges touched).  Passing ``edges`` (a prepared ``EdgeSet``) routes the
     gather⊕combine through the fused GAS kernel with active-block skipping
-    (DESIGN.md §3.5); the dense path gathers all E edges regardless of mask.
+    (DESIGN.md §3.5), with ``weights`` as ``fused_apply_phase`` takes them;
+    the dense path gathers all E edges regardless of mask.
 
     ``residual_dtype`` is the scheduler's priority precision: f32 by
     default, f64 opt-in for tolerance regimes below the f32 residual floor
@@ -107,7 +119,7 @@ def apply_phase(
     """
     if edges is not None:
         return fused_apply_phase(program, graph, mask, glob, edges,
-                                 interpret=interpret,
+                                 weights=weights, interpret=interpret,
                                  residual_dtype=residual_dtype)
     st = graph.structure
     receivers = jnp.asarray(st.receivers)
@@ -140,6 +152,15 @@ def apply_phase(
     return graph, residual, jnp.asarray(st.n_edges, jnp.int32)
 
 
+def _source_degrees(st, leaves) -> Optional[jnp.ndarray]:
+    """Out-degree of each full-edge source, or None: only
+    degree_normalized_src leaves consult it, so don't gather/ship an [E]
+    array otherwise."""
+    if any(leaf.kind == "degree_normalized_src" for leaf in leaves):
+        return jnp.asarray(st.out_degree[st.senders])
+    return None
+
+
 def fused_apply_phase(
     program: VertexProgram,
     graph: DataGraph,
@@ -147,6 +168,7 @@ def fused_apply_phase(
     glob: Pytree,
     edges: EdgeSet,
     *,
+    weights: Optional[Sequence[jnp.ndarray]] = None,
     interpret: Optional[bool] = None,
     residual_dtype=jnp.float32,
 ) -> Tuple[DataGraph, jnp.ndarray, jnp.ndarray]:
@@ -154,31 +176,36 @@ def fused_apply_phase(
     no [E, D] message materialization, inactive row blocks skipped.
 
     Per leaf: the per-vertex feature table ``[N, ...]`` and the per-edge
-    scalar weight ``[E]`` are formed outside the kernel (both sub-[E, D]),
-    the kernel streams the ``edges`` subset and accumulates in VMEM.  Rows
-    outside active blocks come back as zeros; they belong to unscheduled
-    vertices whose apply output is discarded by ``masked_update`` and whose
-    residual is masked below, so the fixed point matches the dense path.
+    scalar weight are formed outside the kernel (both sub-[E, D]), the
+    kernel streams the ``edges`` subset and accumulates in VMEM.  The
+    weights are evaluated on the full edge data, or, for a subset of the
+    edges (a color's), come prepared in ``weights``: one ``[E_pad]`` array
+    per leaf in the subset's edge order (``Engine.prepare_weights``).
+    Rows outside active blocks come back as zeros; they belong to
+    unscheduled vertices whose apply output is discarded by
+    ``masked_update`` and whose residual is masked below, so the fixed
+    point matches the dense path.
     """
     st = graph.structure
     leaves, treedef = fused_gather_leaves(program)
+    assert weights is not None or edges.perm is None, \
+        "a subset of the edges takes its weights prepared"
     with jax.named_scope("graphlab.gather"):
         block_active = active_row_blocks(mask)
-    # out-degree of each full-edge source — only degree_normalized_src
-    # leaves consult it, so don't gather/ship an [E] array otherwise
-    src_deg = jnp.asarray(st.out_degree[st.senders]) if any(
-        leaf.kind == "degree_normalized_src" for leaf in leaves) else None
+    src_deg = _source_degrees(st, leaves) if weights is None else None
 
     acc_leaves = []
-    for leaf in leaves:
+    for i, leaf in enumerate(leaves):
         with jax.named_scope("graphlab.gather"):
             feat = leaf.feature(graph.vertex_data)
         trailing = feat.shape[1:]
         feat2 = feat.reshape(st.n_vertices, -1)
-        with jax.named_scope("graphlab.edge_weight"):
-            w = fused_edge_weight(leaf, graph.edge_data, st.n_edges, src_deg)
-            if edges.perm is not None:
-                w = w[edges.perm]
+        if weights is not None:
+            w = weights[i]
+        else:
+            with jax.named_scope("graphlab.edge_weight"):
+                w = fused_edge_weight(leaf, graph.edge_data, st.n_edges,
+                                      src_deg)
         acc = gather_combine(feat2, w, edges, block_active=block_active,
                              interpret=interpret)
         acc_leaves.append(acc.reshape((st.n_vertices,) + trailing))
@@ -420,16 +447,27 @@ class Engine:
         # per color-step out of the coloring.
         static = stream_tables is None
         self._phase_groups, gas = ([], None)
+        # per phase group, the gather set's map from its edges into the
+        # full edge arrays (None where it gathers over all of them): the
+        # fused weights of a color's edges are gathered through it once
+        # per edge data (``prepare_weights``), so the step carries no perm
+        self._gas_perms: list = []
         if self.use_fused and static:
             with span("graphlab.edge_sets"):
                 self._phase_groups, gas = self._phase_edge_sets()
+            self._gas_perms = [sets["gather"].perm for sets in gas]
+            gas = [dict(sets, gather=dataclasses.replace(sets["gather"],
+                                                         perm=None))
+                   for sets in gas]
+        self._prepares_weights = any(p is not None for p in self._gas_perms)
+        self._jit_weights = jax.jit(self._gas_weights)
         self._consts = {
             "gas": gas,
             "colors": (self.scheduler.colors
                        if static and isinstance(self.scheduler,
                                                 SweepScheduler) else None)}
         self._trace_count = 0  # bumped at trace time; delta tests assert 0 new
-        self._jit_step = jax.jit(self._step)
+        self._jit_step = jax.jit(self._step_unweighted)
 
     def _make_scheduler(self) -> Scheduler:
         """Default schedule when none is passed: a single-color sweep
@@ -483,6 +521,35 @@ class Engine:
         return ([(0, self.scheduler.num_phases)],
                 [stack_edge_sets([{"gather": full, "scatter": full}])])
 
+    def _gas_weights(self, edge_data, perms, src_deg):
+        """Every fused leaf's edge weights, evaluated on the full edge data
+        and gathered into each color-step's edge order: per phase group,
+        per leaf, the group's ``[phases, E_pad]`` rows laid end to end in
+        one f32 vector (() for a group that gathers over all edges).  Flat,
+        because the TPU tiles a 2-D array's rows in eights: a group of one
+        phase would take eight times its bytes."""
+        leaves, _ = fused_gather_leaves(self.program)
+        full = [fused_edge_weight(leaf, edge_data, self.structure.n_edges,
+                                  src_deg) for leaf in leaves]
+        return tuple(() if perm is None else
+                     tuple(w[perm].reshape(-1) for w in full)
+                     for perm in perms)
+
+    def prepare_weights(self, state: EngineState) -> EngineState:
+        """``state`` with ``gas_weights`` prepared from its edge data, where
+        the engine's fused phases gather over a subset of the edges and the
+        state has none.  The fused path runs only programs that never
+        write edges, so the weights hold until ``graph.edge_data`` is
+        replaced (``EngineState.replace`` then drops them)."""
+        if not self._prepares_weights or state.gas_weights:
+            return state
+        src_deg = _source_degrees(self.structure,
+                                  fused_gather_leaves(self.program)[0])
+        with span("graphlab.weights"):
+            weights = jax.block_until_ready(self._jit_weights(
+                state.graph.edge_data, self._gas_perms, src_deg))
+        return state.replace(gas_weights=weights)
+
     def _scatter_ctx(self, tables, sets) -> Optional[ScatterCtx]:
         """ScatterCtx for the fused reschedule (DESIGN.md §3.14), or None
         to keep the dense scatter.  Gated on f32 priorities: the f64
@@ -525,8 +592,12 @@ class Engine:
             select_tables = {"colors": consts["colors"]}
         prev_vdata = state.graph.vertex_data
         glob = state.globals_
+        gas_weights = state.gas_weights
+        if self._prepares_weights and not gas_weights:
+            raise ValueError("the state carries no prepared edge weights: "
+                             "pass it through Engine.prepare_weights")
 
-        def run_phase(phase, sets, carry):
+        def run_phase(phase, sets, carry, weights=None):
             graph, prio, sched, count, total, edges_t = carry
             with jax.named_scope("graphlab.select"):
                 mask, sched = self.scheduler.select(sched, prio, phase,
@@ -535,7 +606,7 @@ class Engine:
                 graph, residual, et = apply_phase(
                     self.program, graph, mask, glob,
                     edges=sets["gather"] if sets else None,
-                    interpret=self.gas_interpret,
+                    weights=weights, interpret=self.gas_interpret,
                     residual_dtype=self.residual_dtype)
             else:
                 graph, residual, et, bump = stream_apply_phase(
@@ -567,17 +638,21 @@ class Engine:
             for phase in range(self.scheduler.num_phases):
                 carry = run_phase(phase, None, carry)
         else:
-            for (first, n), stacked in zip(self._phase_groups,
-                                           consts["gas"]):
+            for g, ((first, n), stacked) in enumerate(
+                    zip(self._phase_groups, consts["gas"])):
                 shared = jax.tree.leaves(stacked)[0].shape[0] == 1
+                group_w = gas_weights[g] if gas_weights else ()
 
                 def body(phase, c, first=first, stacked=stacked,
-                         shared=shared):
+                         shared=shared, group_w=group_w):
+                    i = 0 if shared else phase - first
                     with jax.named_scope("graphlab.edge_sets"):
-                        sets = jax.tree.map(
-                            lambda x: x[0 if shared else phase - first],
-                            stacked)
-                    return run_phase(phase, sets, c)
+                        sets = jax.tree.map(lambda x: x[i], stacked)
+                    e_pad = sets["gather"].senders.shape[0]
+                    with jax.named_scope("graphlab.edge_weight"):
+                        weights = [jax.lax.dynamic_slice_in_dim(
+                            w, i * e_pad, e_pad) for w in group_w] or None
+                    return run_phase(phase, sets, c, weights)
 
                 if n == 1:
                     carry = body(first, carry)
@@ -585,11 +660,19 @@ class Engine:
                     carry = jax.lax.fori_loop(first, first + n, body, carry)
 
         graph, prio, sched, count, total, edges_t = carry
+        # the carry's edge data are new tracers: keep the weights explicitly
         state = state.replace(
             graph=graph, prio=prio, sched=sched, update_count=count,
             total_updates=total, edges_touched=edges_t,
-            step_index=state.step_index + 1)
+            step_index=state.step_index + 1, gas_weights=gas_weights)
         return self._run_syncs(state, prev_vdata)
+
+    def _step_unweighted(self, state: EngineState, tables=None,
+                         consts=None) -> EngineState:
+        """``_step`` without ``gas_weights`` in its output: they pass
+        through unchanged, and as an output of the compiled step they
+        would be copied every step.  ``step`` puts the input's back."""
+        return self._step(state, tables, consts).replace(gas_weights=())
 
     # -- shared driver --------------------------------------------------------
     def init(self, graph: DataGraph, initial_prio=None) -> EngineState:
@@ -599,15 +682,19 @@ class Engine:
             if self.residual_dtype != jnp.float32:
                 state = state.replace(
                     prio=state.prio.astype(self.residual_dtype))
-            return jax.block_until_ready(state)
+            state = jax.block_until_ready(state)
+        return self.prepare_weights(state)
 
     def step(self, state: EngineState) -> EngineState:
-        return self._jit_step(state, self._tables, self._consts)
+        state = self.prepare_weights(state)
+        out = self._jit_step(state, self._tables, self._consts)
+        return out.replace(gas_weights=state.gas_weights)
 
     def compile(self, state: EngineState):
         """Compiles the step ahead of time for ``state``'s shapes; ``step``
         and ``run`` then call that executable.  Returns it, for its
         ``as_text()`` and ``memory_analysis()``."""
+        state = self.prepare_weights(state)
         with span("graphlab.lower"):
             lowered = self._jit_step.lower(state, self._tables, self._consts)
         with span("graphlab.compile"):
@@ -653,7 +740,8 @@ class Engine:
         and timeline spans.  Host spans (``obs.span``): ``graphlab.run``
         over the call, and per step ``graphlab.done`` (the scheduler's
         check, which blocks on the device) and ``graphlab.dispatch``
-        (``step``).
+        (``step``).  A state that lacks its prepared edge weights gets
+        them first (``prepare_weights``, span ``graphlab.weights``).
         """
         from repro.obs.metrics import RowCollector, lazy_local_row
         every = int(trace_every) if trace_every is not None \
@@ -661,6 +749,7 @@ class Engine:
         want_rows = (trace_fn is not None or self.obs.enabled
                      or session is not None)
         col = RowCollector(every, session=session)
+        state = self.prepare_weights(state)
         with span("graphlab.run", session=session, track="local"):
             for _ in range(max_steps):
                 with span("graphlab.done", session=session, track="local"):
@@ -697,4 +786,4 @@ class Engine:
 
         return jax.lax.while_loop(
             cond, lambda s: self._step(s, self._tables, self._consts),
-            state)
+            self.prepare_weights(state))

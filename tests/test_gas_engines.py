@@ -82,6 +82,155 @@ class TestChromaticEquivalence:
         assert np.abs(bf - bd).max() <= TOL
 
 
+def _fixed_point_in_calls(engine, graph, leaf, calls):
+    """The fixed point reached in several ``run`` calls of ``calls`` steps
+    each, then again from the initial state in one call of their sum:
+    returns both answers."""
+    state0 = engine.init(graph)
+    state = state0
+    for k in calls:
+        state, _ = engine.run(state, max_steps=k)
+    again, _ = engine.run(state0, max_steps=sum(calls))
+    return (np.asarray(state.graph.vertex_data[leaf]),
+            np.asarray(again.graph.vertex_data[leaf]))
+
+
+@pytest.mark.parametrize("interpret,tol,calls", [
+    (None, 1e-6, (5, 10, 45)),          # the jnp oracle, to convergence
+    (True, 1e-4, (3, 3, 2)),            # the Pallas kernel body
+], ids=["oracle", "interpret"])
+def test_pagerank_fused_matches_dense_over_calls_and_restart(
+        pagerank_setup, interpret, tol, calls):
+    """The prepared weights serve every ``run`` call and a restart from
+    the initial state: each agrees with the dense path, and the restart
+    repeats the answer of the calls bit for bit."""
+    prog, g = pagerank_setup
+    dense = ChromaticEngine(prog, g, tolerance=tol, use_fused=False)
+    fused = ChromaticEngine(prog, g, tolerance=tol, use_fused=True,
+                            gas_interpret=interpret)
+    rd, _ = _fixed_point(dense, g, "rank", max_steps=sum(calls))
+    rf, again = _fixed_point_in_calls(fused, g, "rank", calls)
+    assert np.abs(rf - rd).max() <= TOL
+    np.testing.assert_array_equal(again, rf)
+
+
+class TestPreparedWeights:
+    """The fused gather's per-color edge weights are prepared once per edge
+    data (``Engine.prepare_weights``) and carried in the state."""
+
+    @staticmethod
+    def _weights_count():
+        from repro.obs import span_totals
+        total = span_totals().get("graphlab.weights")
+        return total.count if total else 0
+
+    @staticmethod
+    def _case(app):
+        if app == "pagerank":
+            st = power_law_graph(600, avg_degree=8, seed=11)
+            return (PageRankProgram(n_vertices=st.n_vertices),
+                    make_pagerank_graph(st))
+        g, _ = make_als_graph(30, 35, 1000, d=4, seed=1)
+        return ALSProgram(d=4), g
+
+    @pytest.mark.parametrize("app", ["pagerank", "als"])
+    def test_prepared_equal_numpy_weights_by_perm(self, app):
+        from repro.core.update import fused_gather_leaves
+        prog, g = self._case(app)
+        eng = ChromaticEngine(prog, g, tolerance=1e-4)
+        state = eng.init(g)
+        leaves, _ = fused_gather_leaves(prog)
+        edata = {k: np.asarray(v) for k, v in g.edge_data.items()}
+        full = [np.asarray(leaf.weight(edata)).astype(np.float32)
+                for leaf in leaves]
+        assert len(state.gas_weights) == len(eng._phase_groups)
+        for (first, n), perm, group in zip(eng._phase_groups, eng._gas_perms,
+                                           state.gas_weights):
+            perm = np.asarray(perm)
+            assert perm.shape[0] == n and len(group) == len(leaves)
+            for w, prepared in zip(full, group):
+                assert prepared.dtype == jnp.float32
+                np.testing.assert_array_equal(np.asarray(prepared),
+                                              w[perm].reshape(-1))
+        # the step gathers nothing through a perm: the sets it takes carry
+        # none
+        assert all(s["gather"].perm is None for s in eng._consts["gas"])
+
+    @pytest.mark.parametrize("make", [
+        lambda p, g: BSPEngine(p, g, tolerance=1e-6, use_fused=True),
+        lambda p, g: DynamicEngine(p, g, pipeline_length=64, tolerance=1e-6,
+                                   use_fused=True),
+    ], ids=["bsp", "dynamic"])
+    def test_full_edge_engines_prepare_nothing(self, pagerank_setup, make):
+        prog, g = pagerank_setup
+        eng = make(prog, g)
+        before = self._weights_count()
+        state = eng.init(g)
+        assert eng.use_fused and state.gas_weights == ()
+        state, _ = eng.run(state, max_steps=3)
+        assert state.gas_weights == ()
+        assert self._weights_count() == before
+
+    def test_replaced_edge_data_is_prepared_again(self):
+        prog, g = self._case("pagerank")
+        eng = ChromaticEngine(prog, g, tolerance=1e-6)
+        state0 = eng.init(g)
+        # host code that keeps the edge data keeps the weights
+        kept = state0.replace(graph=state0.graph.replace(
+            vertex_data=dict(state0.graph.vertex_data)))
+        assert kept.gas_weights is state0.gas_weights
+        g2 = g.replace(edge_data={"w": g.edge_data["w"] * 0.75})
+        stale = state0.replace(graph=state0.graph.replace(
+            edge_data=g2.edge_data))
+        assert stale.gas_weights == ()
+        before = self._weights_count()
+        got, _ = eng.run(stale, max_steps=60)
+        assert self._weights_count() == before + 1
+        fresh = ChromaticEngine(prog, g2, colors=eng.colors, tolerance=1e-6)
+        want, _ = fresh.run(fresh.init(g2), max_steps=60)
+        np.testing.assert_array_equal(
+            np.asarray(got.graph.vertex_data["rank"]),
+            np.asarray(want.graph.vertex_data["rank"]))
+        assert int(got.step_index) == int(want.step_index)
+
+    def test_step_gathers_no_weights_and_prepares_once(self):
+        """The compiled step holds no ``gather`` under
+        ``graphlab.edge_weight`` (only each color-step's slice of its
+        prepared row), and ``init`` plus three ``run`` calls from the
+        initial state prepare once."""
+        import importlib.util
+        import os
+        import re
+        path = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "bench", "scopes.py")
+        spec = importlib.util.spec_from_file_location("bench_scopes", path)
+        scopes = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(scopes)
+
+        prog, g = self._case("pagerank")
+        before = self._weights_count()
+        eng = ChromaticEngine(prog, g, tolerance=1e-6)
+        state0 = eng.init(g)
+        assert [n for _, n in eng._phase_groups].count(1) >= 1
+        assert max(n for _, n in eng._phase_groups) > 1
+        text = eng.compile(state0).as_text()
+        scope_of = scopes.scope_map(text)
+        opcodes = []
+        for line in text.splitlines():
+            m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*\S+\s+"
+                         r"([\w\-]+)\(", line)
+            if m and scope_of.get(m.group(1)) == "graphlab.edge_weight":
+                opcodes.append(m.group(2))
+        assert "dynamic-slice" in opcodes
+        assert "gather" not in opcodes, opcodes
+        for _ in range(3):
+            state, _ = eng.run(state0, max_steps=4)
+        assert self._weights_count() == before + 1
+        # the step hands the same weights on instead of copying them
+        assert all(a is b for a, b in zip(jax.tree.leaves(state.gas_weights),
+                                          jax.tree.leaves(state0.gas_weights)))
+
+
 class TestEdgesTouched:
     def test_first_sweep_below_dense(self, pagerank_setup):
         """Everything scheduled: a fused sweep touches exactly E edges
