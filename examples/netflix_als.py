@@ -4,11 +4,14 @@
 
 Reproduces Fig. 1(d) (non-serializable dynamic ALS is unstable) and
 Fig. 9(a) (dynamic scheduling reaches the same test error in roughly half
-the updates of a static BSP schedule).
+the updates of a static BSP schedule).  Last, ALS-WR (``λ·n_v·I``, each
+vertex's regulariser weighted by its number of training ratings) on the
+chromatic engine's fused GAS path, as the benchmark's Netflix cell runs it.
 """
 import numpy as np
 
-from repro.apps.als import ALSProgram, als_rmse, make_als_graph
+from repro.apps.als import (ALSProgram, als_rmse, make_als_graph,
+                            with_rating_counts)
 from repro.core import BSPEngine, ChromaticEngine, DynamicEngine
 
 D = 8
@@ -48,3 +51,10 @@ if __name__ == "__main__":
     swings = np.abs(np.diff(rmse_racing)).max() if len(rmse_racing) > 1 else 0
     print(f"racing max RMSE swing between steps: {swings:.4f} "
           "(instability signature)")
+
+    wr_graph = with_rating_counts(graph)
+    # λ·n_v with n_v ~ 30 training ratings: about the 0.05 of reg·I above
+    wr = ChromaticEngine(ALSProgram(d=D, reg=0.002, weighted=True),
+                         wr_graph, tolerance=TOL)
+    assert wr.use_fused
+    trace_run(wr, wr_graph, "Chromatic ALS-WR (fused GAS path)")

@@ -7,6 +7,12 @@ the least-squares solution for one vertex from its neighbors' factors:
 
     x_v = (sum_u x_u x_u^T + lambda I)^{-1} (sum_u r_uv x_u)
 
+or, with ALS-WR's weighted-lambda-regularization (Zhou, Wilkinson,
+Schreiber & Pan, "Large-Scale Parallel Collaborative Filtering for the
+Netflix Prize", AAIM 2008, section 3), ``lambda n_v I`` in place of
+``lambda I``, where ``n_v`` is v's number of training ratings: a static
+vertex field (``with_rating_counts``), so the gather keeps its two leaves.
+
 Because the graph is bipartite (2-colorable) and edge consistency suffices,
 the chromatic engine runs it exactly as the paper does.  The *dynamic* ALS
 of Fig. 1(d)/9(a) schedules a vertex's neighbors only on significant factor
@@ -36,9 +42,13 @@ class ALSProgram(VertexProgram):
     consistency = Consistency.EDGE
     schedule_neighbors = True
 
-    def __init__(self, d: int, reg: float = 0.05):
+    def __init__(self, d: int, reg: float = 0.05, weighted: bool = False):
+        """``weighted``: ALS-WR's ``reg · n_v · I``, reading ``n_v`` from
+        the vertex field ``n_train`` (``with_rating_counts``); a vertex with no
+        training rating counts as one, so its factor solves to zero."""
         self.d = int(d)
         self.reg = float(reg)
+        self.weighted = bool(weighted)
 
     def gather(self, ctx: EdgeCtx):
         x = ctx.src["factor"]                      # [E, d]
@@ -66,11 +76,17 @@ class ALSProgram(VertexProgram):
 
     def apply(self, vertex_data, acc, glob=None) -> ApplyOut:
         d = self.d
-        A = acc["xxt"] + self.reg * jnp.eye(d, dtype=acc["xxt"].dtype)
+        reg = self.reg
+        if self.weighted:
+            reg = reg * jnp.maximum(vertex_data["n_train"], 1.0)[:, None, None]
+        A = acc["xxt"] + reg * jnp.eye(d, dtype=acc["xxt"].dtype)
         b = acc["rx"]
-        new = jnp.linalg.solve(A, b[..., None])[..., 0]
+        # the solve in float32 whatever the default matmul precision (the
+        # MXU's default rounds a matmul's products to bf16)
+        with jax.default_matmul_precision("highest"):
+            new = jnp.linalg.solve(A, b[..., None])[..., 0]
         residual = jnp.sum(jnp.abs(new - vertex_data["factor"]), axis=-1)
-        return ApplyOut({"factor": new}, residual)
+        return ApplyOut({**vertex_data, "factor": new}, residual)
 
 
 def make_als_graph(
@@ -116,6 +132,35 @@ def make_als_graph(
     info = {"n_users": n_users, "n_movies": n_movies,
             "user_of": user_of, "movie_of": movie_of}
     return g, info
+
+
+def ratings_graph(users: np.ndarray, movies: np.ndarray, n_users: int,
+                  n_movies: int, rating: np.ndarray, train: np.ndarray,
+                  factors: np.ndarray, dtype=jnp.float32) -> DataGraph:
+    """The ALS-WR data graph of given ratings: each (user, movie) pair once
+    (movies numbered from 0), its rating and train flag on both directed
+    edges, users first then movies as vertices with their initial
+    ``factors`` and ``n_train`` (``with_rating_counts``)."""
+    st, perm = GraphStructure.undirected(users, np.asarray(movies) + n_users,
+                                         n_users + n_movies)
+    train = np.concatenate([train, train])[perm].astype(np.float32)
+    rating = np.concatenate([rating, rating])[perm]
+    return with_rating_counts(DataGraph.build(
+        st, {"factor": jnp.asarray(factors, dtype)},
+        {"rating": jnp.asarray(rating, dtype),
+         "train": jnp.asarray(train, dtype)}))
+
+
+def with_rating_counts(graph: DataGraph) -> DataGraph:
+    """``graph`` with the vertex field ``n_train`` that ALS-WR
+    (``ALSProgram(weighted=True)``) reads: each vertex's number of
+    training ratings, its in-edges weighted by their ``train`` flag."""
+    st = graph.structure
+    n = np.bincount(st.receivers, weights=np.asarray(graph.edge_data["train"]),
+                    minlength=st.n_vertices)
+    return graph.replace(vertex_data={
+        **graph.vertex_data,
+        "n_train": jnp.asarray(n, graph.vertex_data["factor"].dtype)})
 
 
 def als_rmse(graph: DataGraph, train: bool) -> float:

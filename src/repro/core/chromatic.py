@@ -32,10 +32,21 @@ from repro.core.graph import DataGraph
 from repro.core.scheduler import SweepScheduler
 from repro.core.sync_op import SyncOp
 from repro.core.update import VertexProgram
-from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
+from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK
 from repro.kernels.gas.ops import (EdgeSet, color_runs, size_class,
                                    split_by_color, stack_edge_sets)
 from repro.obs.timeline import span
+
+
+def steps_bound(n_edges: int, n_vertices: int) -> int:
+    """The longest kernel grid (``csr_steps``) of ``n_edges``
+    receiver-sorted edges: a step per row block, and one more where an
+    edge block crosses into the next row block.  A schedule is shorter
+    by every row-block boundary that falls on an edge-block boundary,
+    which depends on the vertex labels; padded to this length, every
+    labelling of a graph gives the same step program."""
+    return max(-(-n_vertices // ROW_BLOCK), 1) \
+        + max(-(-n_edges // EDGE_BLOCK) - 1, 0)
 
 
 class ChromaticEngine(Engine):
@@ -106,10 +117,7 @@ class ChromaticEngine(Engine):
             sets = [{} for _ in range(count)]
             for kind, idx in subsets.items():
                 nb = blocks[kind][first]
-                pad = lambda i: np.pad(st.receivers[i],
-                                       (0, nb * EDGE_BLOCK - i.size),
-                                       constant_values=n + ROW_BLOCK)
-                n_steps = max(csr_steps(pad(idx[c]), n)[0].size
+                n_steps = max(steps_bound(idx[c].size, n)
                               for c in range(first, first + count))
                 for j, c in enumerate(range(first, first + count)):
                     sets[j][kind] = EdgeSet.build(

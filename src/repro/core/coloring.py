@@ -13,6 +13,7 @@ import numpy as np
 
 from repro.core.consistency import Consistency
 from repro.core.graph import GraphStructure
+from repro.obs.timeline import span
 
 
 def _csr(structure: GraphStructure) -> Tuple[np.ndarray, np.ndarray]:
@@ -109,29 +110,42 @@ def distance2_coloring(structure: GraphStructure) -> np.ndarray:
 
 
 def bipartite_coloring(structure: GraphStructure) -> Optional[np.ndarray]:
-    """BFS 2-coloring; returns None if the graph is not bipartite."""
-    n = structure.n_vertices
-    s = np.concatenate([structure.senders, structure.receivers])
-    r = np.concatenate([structure.receivers, structure.senders])
-    sort = np.argsort(r, kind="stable")
-    s, r = s[sort], r[sort]
-    offsets = np.concatenate([[0], np.cumsum(np.bincount(r, minlength=n))])
+    """2-coloring by breadth-first search from each component's smallest
+    vertex, which takes color 0; None if the graph is not bipartite.
 
-    colors = np.full(n, -1, dtype=np.int32)
-    for root in range(n):
-        if colors[root] >= 0:
-            continue
-        colors[root] = 0
-        stack = [root]
-        while stack:
-            v = stack.pop()
-            for u in s[offsets[v]:offsets[v + 1]]:
-                if colors[u] < 0:
-                    colors[u] = 1 - colors[v]
-                    stack.append(int(u))
-                elif colors[u] == colors[v]:
-                    return None
-    return colors
+    Level-synchronous and vectorised: every vertex starts as its own root
+    with key ``2·v`` (root ``v``, parity 0), and each round every vertex
+    takes the smallest of its key and its neighbors' keys with the parity
+    flipped.  After as many rounds as the farthest vertex is from its
+    component's smallest vertex, each key holds that root and the parity
+    of a path from it: the color.  A key is always the root and parity of
+    a real walk, so an edge whose two ends hold the same key closes a walk
+    of odd length: the graph has an odd cycle, and the search stops there.
+    """
+    with span("graphlab.bipartite"):
+        n = structure.n_vertices
+        s, r = structure.senders, structure.receivers    # receiver-sorted
+        if not structure.is_symmetric():
+            s = np.concatenate([structure.senders, structure.receivers])
+            r = np.concatenate([structure.receivers, structure.senders])
+            sort = np.argsort(r, kind="stable")
+            s, r = s[sort], r[sort]
+        if s.size == 0:
+            return np.zeros(n, np.int32)
+        deg = np.bincount(r, minlength=n)
+        has = deg > 0
+        starts = (np.cumsum(deg) - deg)[has]
+        key = 2 * np.arange(n, dtype=np.int64)
+        while True:
+            ks = key[s]
+            if (ks == key[r]).any():
+                return None
+            nxt = key.copy()
+            nxt[has] = np.minimum(key[has],
+                                  np.minimum.reduceat(ks ^ 1, starts))
+            if (nxt == key).all():
+                return (key & 1).astype(np.int32)
+            key = nxt
 
 
 def coloring_for(

@@ -6,7 +6,8 @@ SMEM and VMEM budgets.  These tests compile each engine kernel for one
 chip of a described (not attached) ``v5e:2x2`` topology at deployment
 sizes: 2^20 vertices and 16M edges, for scalar tables (PageRank, every
 scheduler scatter) and for ALS's x·xᵀ table at d = 20 (400 wide, 512
-padded), and the segment sum at the widths its dispatch rule takes.  The
+padded), the Netflix ALS-WR cell's two gathers at its own sizes, and the
+segment sum at the widths its dispatch rule takes.  The
 16M-edge cases guard the SMEM budget: a kernel that scalar-prefetches an
 ``[E]`` array is refused here.  Nothing runs.
 """
@@ -67,6 +68,31 @@ def test_gather_combine_compiles(one_chip, width):
     text = _compiled_text(fn, one_chip, ((N_ROWS, width), jnp.float32),
                           *_edge_shapes(),
                           ((N_ROWS // gas.ROW_BLOCK,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("width", [ALS_XXT, 20])
+def test_als_cell_gathers_compile(one_chip, width):
+    """The two fused gathers of the Netflix ALS-WR cell at its sizes:
+    77,794 vertices and a color-step padded to 2^24 edges.  Its 400-wide
+    x·xᵀ table (159 MB) is staged from HBM; its 20-wide table (39.8 MB)
+    stays resident in VMEM, the largest resident table the cell asks
+    for."""
+    n_rows = 77_794
+    n_steps = N_EDGES // gas.EDGE_BLOCK + -(-n_rows // gas.ROW_BLOCK)
+    planes = -(-width // gas.LANES)
+    resident = planes * -(-n_rows // 8) * 8 * gas.LANES * 4 \
+        <= gas.RESIDENT_BYTES
+    assert resident == (width == 20)
+
+    def fn(feat, w, snd, rcv, step_rb, step_eb, act):
+        return gas.gas_gather_combine_pallas(feat, w, snd, rcv, n_rows,
+                                             step_rb, step_eb, act)
+
+    shapes = _edge_shapes()[:3] + [((n_steps,), jnp.int32)] * 2
+    text = _compiled_text(fn, one_chip, ((n_rows, width), jnp.float32),
+                          *shapes,
+                          ((-(-n_rows // gas.ROW_BLOCK),), jnp.int32))
     assert "tpu_custom_call" in text
 
 
