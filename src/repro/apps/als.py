@@ -59,14 +59,12 @@ class ALSProgram(VertexProgram):
         }
 
     def fused_gather(self):
-        # Both leaves are weighted-src-sums of *derived* per-vertex features:
-        # the x xᵀ outer product is an [N, d, d] vertex table (cheap — N ≪ E),
-        # so the [E, d, d] per-edge messages never materialize (DESIGN §3.5).
+        # The [E, d, d] per-edge messages never materialize (DESIGN §3.5):
+        # the kernel forms each x xᵀ from the d-wide factor table.
         return {
             "xxt": FusedGather(
-                "weighted_src_sum",
-                feature=lambda v: v["factor"][:, :, None]
-                * v["factor"][:, None, :],
+                "weighted_src_outer",
+                feature=lambda v: v["factor"],
                 weight=lambda e: e["train"]),
             "rx": FusedGather(
                 "weighted_src_sum",

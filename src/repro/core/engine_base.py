@@ -161,6 +161,44 @@ def _source_degrees(st, leaves) -> Optional[jnp.ndarray]:
     return None
 
 
+def leaf_weights(leaves, edge_data: Pytree, n_edges: int,
+                 src_deg: Optional[jnp.ndarray] = None,
+                 edge_mask: Optional[jnp.ndarray] = None) -> list:
+    """Each fused leaf's per-edge weight ``[E]`` on ``edge_data`` (zero
+    where ``edge_mask`` is False)."""
+    with jax.named_scope("graphlab.edge_weight"):
+        ws = [fused_edge_weight(leaf, edge_data, n_edges, src_deg)
+              for leaf in leaves]
+        if edge_mask is not None:
+            ws = [jnp.where(edge_mask, w, 0.0) for w in ws]
+    return ws
+
+
+def gather_leaves(leaves, treedef, vertex_data: Pytree,
+                  weights: Sequence[jnp.ndarray], edges: EdgeSet, *,
+                  block_active: jnp.ndarray,
+                  interpret: Optional[bool] = None) -> Pytree:
+    """The fused gather: per leaf, its feature evaluated on
+    ``vertex_data`` (own rows, then any ghost rows) and combined over
+    ``edges`` with ``weights[i]`` (in ``edges``' order) → the accumulator
+    pytree, ``[edges.n_vertices, ...]`` a leaf; a ``weighted_src_outer``
+    leaf's is ``[n, d, d]``."""
+    n = edges.n_vertices
+    accs = []
+    for leaf, w in zip(leaves, weights):
+        with jax.named_scope("graphlab.gather"):
+            feat = leaf.feature(vertex_data)
+        outer = leaf.kind == "weighted_src_outer"
+        if outer:
+            assert feat.ndim == 2, (leaf.kind, feat.shape)
+        trailing = feat.shape[1:] * (2 if outer else 1)
+        acc = gather_combine(feat.reshape(feat.shape[0], -1), w, edges,
+                             block_active=block_active, interpret=interpret,
+                             outer=outer)
+        accs.append(acc.reshape((n,) + trailing))
+    return jax.tree.unflatten(treedef, accs)
+
+
 def fused_apply_phase(
     program: VertexProgram,
     graph: DataGraph,
@@ -192,24 +230,11 @@ def fused_apply_phase(
         "a subset of the edges takes its weights prepared"
     with jax.named_scope("graphlab.gather"):
         block_active = active_row_blocks(mask)
-    src_deg = _source_degrees(st, leaves) if weights is None else None
-
-    acc_leaves = []
-    for i, leaf in enumerate(leaves):
-        with jax.named_scope("graphlab.gather"):
-            feat = leaf.feature(graph.vertex_data)
-        trailing = feat.shape[1:]
-        feat2 = feat.reshape(st.n_vertices, -1)
-        if weights is not None:
-            w = weights[i]
-        else:
-            with jax.named_scope("graphlab.edge_weight"):
-                w = fused_edge_weight(leaf, graph.edge_data, st.n_edges,
-                                      src_deg)
-        acc = gather_combine(feat2, w, edges, block_active=block_active,
-                             interpret=interpret)
-        acc_leaves.append(acc.reshape((st.n_vertices,) + trailing))
-    acc = jax.tree.unflatten(treedef, acc_leaves)
+    if weights is None:
+        weights = leaf_weights(leaves, graph.edge_data, st.n_edges,
+                               _source_degrees(st, leaves))
+    acc = gather_leaves(leaves, treedef, graph.vertex_data, weights, edges,
+                        block_active=block_active, interpret=interpret)
 
     with jax.named_scope("graphlab.apply"):
         new_v, residual = program.apply(graph.vertex_data, acc, glob)
@@ -273,20 +298,10 @@ def stream_apply_phase(
                      receivers=rcv, step_rb=step_rb, step_eb=step_eb)
         src_deg_e = tables["out_deg"][senders] if any(
             leaf.kind == "degree_normalized_src" for leaf in leaves) else None
-        acc_leaves = []
-        for leaf in leaves:
-            with jax.named_scope("graphlab.gather"):
-                feat = leaf.feature(graph.vertex_data)
-            trailing = feat.shape[1:]
-            with jax.named_scope("graphlab.edge_weight"):
-                w = fused_edge_weight(leaf, graph.edge_data, e_cap,
-                                      src_deg_e)
-                w = jnp.where(emask, w, 0.0)
-            acc = gather_combine(feat.reshape(n, -1), w, es,
-                                 block_active=block_active,
-                                 interpret=interpret)
-            acc_leaves.append(acc.reshape((n,) + trailing))
-        acc = jax.tree.unflatten(treedef, acc_leaves)
+        weights = leaf_weights(leaves, graph.edge_data, e_cap, src_deg_e,
+                               edge_mask=emask)
+        acc = gather_leaves(leaves, treedef, graph.vertex_data, weights, es,
+                            block_active=block_active, interpret=interpret)
         with jax.named_scope("graphlab.gather"):
             edges_touched = jnp.sum(
                 jnp.where(block_active > 0, tables["block_counts"], 0)
@@ -529,8 +544,8 @@ class Engine:
         because the TPU tiles a 2-D array's rows in eights: a group of one
         phase would take eight times its bytes."""
         leaves, _ = fused_gather_leaves(self.program)
-        full = [fused_edge_weight(leaf, edge_data, self.structure.n_edges,
-                                  src_deg) for leaf in leaves]
+        full = leaf_weights(leaves, edge_data, self.structure.n_edges,
+                            src_deg)
         return tuple(() if perm is None else
                      tuple(w[perm].reshape(-1) for w in full)
                      for perm in perms)

@@ -32,13 +32,17 @@ Pytree = Any
 # ---------------------------------------------------------------------------
 
 #: The registry of gather shapes the fused GAS kernel can compute in-kernel.
-#: Every kind reduces to ``acc[v] = Σ_{u→v} w_e · feature(u)`` for a
-#: per-vertex feature table and a per-edge scalar weight — the pieces the
-#: kernel consumes without ever materializing the [E, D] messages:
-#:   weighted_src_sum      w_e = ``weight(edge_data)``
-#:   src_copy              w_e = 1
-#:   degree_normalized_src w_e = 1 / max(out_degree(u), 1)
-FUSED_GATHER_KINDS = ("weighted_src_sum", "src_copy", "degree_normalized_src")
+#: Every kind reduces to ``acc[v] = Σ_{u→v} w_e · m(u)`` for a per-vertex
+#: feature table and a per-edge scalar weight — the pieces the kernel
+#: consumes without ever materializing the [E, D] messages:
+#:   weighted_src_sum      m = feature(u), w_e = ``weight(edge_data)``
+#:   src_copy              m = feature(u), w_e = 1
+#:   degree_normalized_src m = feature(u), w_e = 1 / max(out_degree(u), 1)
+#:   weighted_src_outer    m = feature(u) feature(u)ᵀ for an [N, d] feature
+#:                         (accumulator [N, d, d]), w_e = ``weight(...)``
+FUSED_GATHER_KINDS = ("weighted_src_sum", "src_copy", "degree_normalized_src",
+                      "weighted_src_outer")
+WEIGHTED_KINDS = ("weighted_src_sum", "weighted_src_outer")
 
 
 class FusedGather(NamedTuple):
@@ -46,9 +50,10 @@ class FusedGather(NamedTuple):
 
     ``feature`` maps vertex data to a per-vertex array ``[N, ...]`` (any
     trailing shape — it is flattened for the kernel and restored on the
-    accumulator); ``weight`` maps edge data to a per-edge scalar ``[E]``
-    (``weighted_src_sum`` only).  The declaration must compute exactly what
-    ``gather`` computes — engines fuse it, tests cross-check the two.
+    accumulator; ``[N, d]`` for ``weighted_src_outer``); ``weight`` maps
+    edge data to a per-edge scalar ``[E]`` (the ``WEIGHTED_KINDS`` only).
+    The declaration must compute exactly what ``gather`` computes —
+    engines fuse it, tests cross-check the two.
     """
 
     kind: str
@@ -71,8 +76,8 @@ def fused_gather_leaves(program) -> Optional[Tuple[list, Any]]:
         if leaf.kind not in FUSED_GATHER_KINDS:
             raise ValueError(f"unknown fused gather kind {leaf.kind!r} "
                              f"(registry: {FUSED_GATHER_KINDS})")
-        if leaf.kind == "weighted_src_sum" and leaf.weight is None:
-            raise ValueError("weighted_src_sum needs a weight fn")
+        if leaf.kind in WEIGHTED_KINDS and leaf.weight is None:
+            raise ValueError(f"{leaf.kind} needs a weight fn")
     return leaves, treedef
 
 
@@ -91,7 +96,7 @@ def fused_edge_weight(leaf: FusedGather, edge_data: Pytree, n_edges: int,
     ``src_deg`` (out-degree of each edge's source) is only consulted by
     ``degree_normalized_src`` — callers materialize it lazily so the common
     weighted/copy kinds never pay an O(E) degree gather."""
-    if leaf.kind == "weighted_src_sum":
+    if leaf.kind in WEIGHTED_KINDS:
         return leaf.weight(edge_data).astype(jnp.float32)
     if leaf.kind == "src_copy":
         return jnp.ones(n_edges, jnp.float32)

@@ -47,7 +47,8 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core.coloring import coloring_for
-from repro.core.engine_base import edge_residual_bump
+from repro.core.engine_base import (edge_residual_bump, gather_leaves,
+                                    leaf_weights)
 from repro.core.graph import DataGraph, segment_combine
 from repro.core.scheduler import sweep_mask
 from repro.core.snapshot import SnapshotState, stitch_rows
@@ -57,16 +58,15 @@ from repro.dist.snapshot import (assemble_snapshot as _assemble_snapshot,
 from repro.core.partition import (atom_meta_index, overpartition,
                                   place_atoms)
 from repro.core.sync_op import SyncOp, run_syncs
-from repro.core.update import (EdgeCtx, VertexProgram, fused_edge_weight,
-                               fused_gather_leaves, masked_update,
-                               supports_fused_gather)
+from repro.core.update import (EdgeCtx, VertexProgram, fused_gather_leaves,
+                               masked_update, supports_fused_gather)
 from repro.dist.wire import (WireConfig, decode_payload, encode_payload,
                              encode_rows, payload_row_nbytes,
                              tree_add_where, tree_rows_maxabs, tree_sub)
 from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
 from repro.kernels.gas.ops import (EdgeSet, active_row_blocks, color_runs,
-                                   gather_combine, scatter_reschedule,
-                                   size_class, split_by_color)
+                                   scatter_reschedule, size_class,
+                                   split_by_color)
 from repro.obs.timeline import span
 
 Pytree = Any
@@ -783,22 +783,14 @@ class ShardEngineBase:
                 es_dep, perm = es, None
                 if group is not None:
                     es, es_dep, perm = color_edge_sets(tb, color, group)
-                blk_active = active_row_blocks(active)
-                accs = []
-                for leaf in gas_leaves:
-                    feat = leaf.feature(v_all)
-                    trailing = feat.shape[1:]
-                    w = fused_edge_weight(leaf, edata, e_loc,
-                                          tb["src_deg_e"])
-                    w = jnp.where(tb["edge_mask"], w, 0.0)
-                    if perm is not None:
-                        w = w[perm]
-                    a = gather_combine(
-                        feat.reshape(feat.shape[0], -1), w, es,
-                        block_active=blk_active,
-                        interpret=gas_interpret)
-                    accs.append(a.reshape((n_loc,) + trailing))
-                acc = jax.tree.unflatten(gas_treedef, accs)
+                weights = leaf_weights(gas_leaves, edata, e_loc,
+                                       tb["src_deg_e"],
+                                       edge_mask=tb["edge_mask"])
+                if perm is not None:
+                    weights = [w[perm] for w in weights]
+                acc = gather_leaves(gas_leaves, gas_treedef, v_all, weights,
+                                    es, block_active=active_row_blocks(active),
+                                    interpret=gas_interpret)
             else:
                 if use_rev:
                     e_all = jax.tree.map(

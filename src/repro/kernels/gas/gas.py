@@ -28,6 +28,17 @@ layouts, chosen by the size of what must stay on chip:
     msgs[EB, 128]`` at ``Precision.HIGHEST`` (the one-hot factor is exact,
     so f32 messages are summed without a bf16 rounding; at the default
     precision the v5e rounds them to bf16).
+  - **row-block, outer products** (``outer=True``: ``acc[v] = Σ_{u→v}
+    w_e · f(u) f(u)ᵀ`` from a ``[N, d]`` table, ALS's ``x xᵀ``).  The
+    ``d``-wide table stays resident, one vertex to a row; each step
+    stages its block's sender rows by dynamic one-row loads, expands
+    them to ``x_i`` and ``x_j`` at every lane ``i·d + j`` of the
+    ``d²``-wide message by two exact 0/1 matmuls (each in three bf16
+    passes over an exact three-piece split of the rows), multiplies the
+    two on the VPU (each product rounded once, as an ``[N, d²]`` table
+    would hold it), and combines the messages with the same one-hot
+    matmul, the edge weights folded into its columns.  The derived table is
+    never built, and no row is fetched by DMA (``kernel_takes_outer``).
 
 Scalar tables too large to stay resident, and features wider than
 ``MAX_FEAT``, keep the dense XLA path (``kernel_takes_gather``,
@@ -117,14 +128,38 @@ def _planes(feat: jnp.ndarray) -> jnp.ndarray:
     return feat.reshape(-1, d_pad // LANES, LANES).transpose(1, 0, 2)
 
 
-def _row_block_kernel(*, n_planes: int, resident: bool):
-    """Row-block gather⊕combine body over an ``n_planes``-plane table."""
+def _outer_table(feat: jnp.ndarray) -> jnp.ndarray:
+    """[N, d] → [1, N_pad, 128]: one vertex to a row, for the outer
+    product path (also at d == 1, which ``_planes`` would pack)."""
+    n, d = feat.shape
+    return jnp.pad(feat.astype(jnp.float32),
+                   ((0, _round_up(n, 8) - n), (0, LANES - d)))[None]
+
+
+def _expansions(d: int, n_planes: int) -> np.ndarray:
+    """[2, n_planes, 128, 128] 0/1 maps of a ``d``-wide row to the
+    ``d²``-wide message: ``S @ E[0]`` puts ``x_i`` and ``S @ E[1]`` puts
+    ``x_j`` at lane ``i·d + j`` (plane ``lane // 128``); lanes past ``d²``
+    stay zero."""
+    lane = np.arange(n_planes * LANES)
+    real = lane < d * d
+    e = np.zeros((2, LANES, n_planes * LANES), np.float32)
+    e[0, (lane // d)[real], lane[real]] = 1.0
+    e[1, (lane % d)[real], lane[real]] = 1.0
+    return e.reshape(2, LANES, n_planes, LANES).transpose(0, 2, 1, 3)
+
+
+def _row_block_kernel(*, n_planes: int, resident: bool, outer: bool = False):
+    """Row-block gather⊕combine body over an ``n_planes``-plane table, or,
+    ``outer``, over the outer products of a one-plane table's rows into
+    ``n_planes`` planes."""
 
     def kernel(srb, seb, act,             # scalar prefetch [T], [T], [RB's]
-               snd, w,                    # SMEM blocks [EB]
+               snd, w,                    # SMEM [EB]; w VMEM [EB] if outer
                recv,                      # VMEM block [EB]
                tab,                       # VMEM-resident or HBM table
-               out, stage, acc, sem):
+               *refs):                    # [expansions,] out, scratch
+        *expand, out, stage, acc, sem = refs
         t = pl.program_id(0)
         n_t = pl.num_programs(0)
         rb, eb = srb[t], seb[t]
@@ -135,8 +170,53 @@ def _row_block_kernel(*, n_planes: int, resident: bool):
         def _init():
             acc[...] = jnp.zeros_like(acc)
 
+        def matmul(a, b):
+            return jax.lax.dot_general(
+                a, b, (((1,), (0,)), ((), ())),
+                precision=_HIGHEST, preferred_element_type=jnp.float32)
+
+        def onehot(value):
+            # ⊕-combine matrix: padding edges carry receivers outside every
+            # row block, so their column is all zero
+            local = recv[...] - rb * ROW_BLOCK
+            rows = jax.lax.broadcasted_iota(jnp.int32,
+                                            (ROW_BLOCK, EDGE_BLOCK), 0)
+            return jnp.where(rows == local[None, :], value, 0.0)
+
+        def accumulate_outer():
+            def load(q, carry):
+                for u in range(_UNROLL):
+                    r = q * _UNROLL + u
+                    stage[0, pl.ds(r, 1), :] = tab[0, pl.ds(snd[r], 1), :]
+                return carry
+
+            jax.lax.fori_loop(0, EDGE_BLOCK // _UNROLL, load, 0)
+            # x = hi + mid + lo exactly, 8 of f32's 24 significant bits a
+            # piece: three bf16 passes against the 0/1 maps place x's
+            # entries exactly (HIGHEST would take six)
+            x = stage[0]
+            hi = x.astype(jnp.bfloat16)
+            rest = x - hi.astype(jnp.float32)
+            mid = rest.astype(jnp.bfloat16)
+            lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+            def spread(e):
+                def mm(piece):
+                    return jax.lax.dot_general(
+                        piece, e, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                return (mm(hi) + mm(mid)) + mm(lo)
+
+            combine = onehot(w[...][None, :])     # the weights in its columns
+            for c in range(n_planes):
+                xi = spread(expand[0][0, c])
+                xj = spread(expand[0][1, c])
+                acc[c] += matmul(combine, xi * xj)
+
         @pl.when((eb >= 0) & (act[rb] > 0))
         def _accumulate():
+            if outer:
+                return accumulate_outer()
             if not resident:
                 # every row DMA of the block in flight on one semaphore
                 def copy(r, c):
@@ -171,16 +251,9 @@ def _row_block_kernel(*, n_planes: int, resident: bool):
 
             jax.lax.fori_loop(0, EDGE_BLOCK // _UNROLL, form, 0)
 
-            # ⊕-combine: padding edges carry receivers outside every row
-            # block, so their one-hot column is all zero
-            local = recv[...] - rb * ROW_BLOCK
-            rows = jax.lax.broadcasted_iota(jnp.int32, (ROW_BLOCK, EDGE_BLOCK),
-                                            0)
-            onehot = jnp.where(rows == local[None, :], 1.0, 0.0)
+            combine = onehot(1.0)
             for c in range(n_planes):
-                acc[c] += jax.lax.dot_general(
-                    onehot, stage[c], (((1,), (0,)), ((), ())),
-                    precision=_HIGHEST, preferred_element_type=jnp.float32)
+                acc[c] += matmul(combine, stage[c])
 
         @pl.when(last)
         def _flush():
@@ -191,33 +264,37 @@ def _row_block_kernel(*, n_planes: int, resident: bool):
 
 
 def _row_block_call(table, weights, senders, receivers, step_rb, step_eb,
-                    block_active, *, n_out_rows, interpret=False):
-    """Runs the row-block kernel over a prepared ``_planes`` table."""
+                    block_active, *, n_out_rows, expand=None, interpret=False):
+    """Runs the row-block kernel over a prepared ``_planes`` table, or,
+    given ``expand`` (``_expansions``), over the outer products of an
+    ``_outer_table``'s rows."""
     e_pad, = weights.shape
     assert e_pad % EDGE_BLOCK == 0, (e_pad,)
-    n_planes = table.shape[0]
+    outer = expand is not None
+    n_planes = expand.shape[1] if outer else table.shape[0]
     width = n_planes * LANES
     table_bytes = table.size * table.dtype.itemsize
     resident = table_bytes <= RESIDENT_BYTES
+    assert resident or not outer, (table_bytes, "outer tables stay resident")
 
     def eblk(t, srb, seb, act):
         return (jnp.maximum(seb[t], 0),)
 
+    smem = pl.BlockSpec((EDGE_BLOCK,), eblk, memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec((EDGE_BLOCK,), eblk)
     return pl.pallas_call(
-        _row_block_kernel(n_planes=n_planes, resident=resident),
+        _row_block_kernel(n_planes=n_planes, resident=resident, outer=outer),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(step_rb.shape[0],),
             in_specs=[
-                pl.BlockSpec((EDGE_BLOCK,), eblk, memory_space=pltpu.SMEM),
-                pl.BlockSpec((EDGE_BLOCK,), eblk, memory_space=pltpu.SMEM),
-                pl.BlockSpec((EDGE_BLOCK,), eblk),
+                smem, vmem if outer else smem, vmem,
                 pl.BlockSpec(memory_space=pltpu.VMEM if resident else pl.ANY),
-            ],
+            ] + [pl.BlockSpec(memory_space=pltpu.VMEM)] * outer,
             out_specs=pl.BlockSpec((ROW_BLOCK, width),
                                    lambda t, srb, seb, act: (srb[t], 0)),
             scratch_shapes=[
-                pltpu.VMEM((n_planes, EDGE_BLOCK, LANES), jnp.float32),
+                pltpu.VMEM((table.shape[0], EDGE_BLOCK, LANES), jnp.float32),
                 pltpu.VMEM((n_planes, ROW_BLOCK, LANES), jnp.float32),
                 pltpu.SemaphoreType.DMA((1,)),
             ],
@@ -229,7 +306,8 @@ def _row_block_call(table, weights, senders, receivers, step_rb, step_eb,
         interpret=interpret,
     )(step_rb.astype(jnp.int32), step_eb.astype(jnp.int32),
       block_active.astype(jnp.int32), senders.astype(jnp.int32),
-      weights.astype(jnp.float32), receivers.astype(jnp.int32), table)
+      weights.astype(jnp.float32), receivers.astype(jnp.int32), table,
+      *([jnp.asarray(expand, jnp.bfloat16)] if outer else []))
 
 
 def _edge_major_kernel(*, reschedule: bool):
@@ -312,6 +390,15 @@ def edge_major_fits(n_src: int, n_rows: int, reschedule: bool) -> bool:
         <= RESIDENT_BYTES
 
 
+def kernel_takes_outer(n_src: int, d: int) -> bool:
+    """The dispatch rule of the fused outer-product gather: the row-block
+    kernel forms the ``d²``-wide messages itself while the ``d``-wide
+    table, one vertex to a row, stays resident and ``d²`` fits
+    ``MAX_FEAT`` lanes; otherwise callers gather a ``[N, d²]`` table."""
+    return (_round_up(d * d, LANES) <= MAX_FEAT
+            and _round_up(n_src, 8) * LANES * 4 <= RESIDENT_BYTES)
+
+
 def kernel_takes_gather(n_src: int, d: int, n_rows: int) -> bool:
     """The dispatch rule of the fused gather: scalar tables while the
     edge-major kernel keeps them resident, wider ones up to ``MAX_FEAT``
@@ -322,7 +409,8 @@ def kernel_takes_gather(n_src: int, d: int, n_rows: int) -> bool:
     return _round_up(d, LANES) <= MAX_FEAT
 
 
-@functools.partial(jax.jit, static_argnames=("n_rows", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("n_rows", "interpret", "outer"))
 def gas_gather_combine_pallas(
     feat: jnp.ndarray,         # [N, D] source-feature table
     weights: jnp.ndarray,      # [E_pad] f32, pad rows 0
@@ -333,14 +421,24 @@ def gas_gather_combine_pallas(
     step_eb: jnp.ndarray,      # [T] i32
     block_active: jnp.ndarray,  # [n_row_blocks] i32 bitmap
     interpret: bool = False,
+    outer: bool = False,
 ) -> jnp.ndarray:
-    """``acc[v] = Σ_{u→v} w_e · feat[u]`` → [n_rows, D] (feat's dtype);
-    rows of inactive row blocks are exact zeros.  Callers check
-    ``kernel_takes_gather``."""
+    """``acc[v] = Σ_{u→v} w_e · feat[u]`` → [n_rows, D] (feat's dtype), or,
+    ``outer``, ``Σ_{u→v} w_e · feat[u] feat[u]ᵀ`` → [n_rows, D²] (row
+    major); rows of inactive row blocks are exact zeros.  Callers check
+    ``kernel_takes_gather`` or ``kernel_takes_outer``."""
     N, D = feat.shape
-    assert kernel_takes_gather(N, D, n_rows), (N, D, n_rows)
     n_rb = max(-(-n_rows // ROW_BLOCK), 1)
     block_active = block_active.astype(jnp.int32)
+    if outer:
+        assert kernel_takes_outer(N, D), (N, D)
+        out = _row_block_call(
+            _outer_table(feat), weights, senders, receivers, step_rb,
+            step_eb, block_active, n_out_rows=n_rb * ROW_BLOCK,
+            expand=_expansions(D, _round_up(D * D, LANES) // LANES),
+            interpret=interpret)
+        return out[:n_rows, :D * D].astype(feat.dtype)
+    assert kernel_takes_gather(N, D, n_rows), (N, D, n_rows)
     if D > 1:
         out = _row_block_call(_planes(feat), weights, senders, receivers,
                               step_rb, step_eb, block_active,

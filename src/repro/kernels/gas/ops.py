@@ -26,7 +26,7 @@ import numpy as np
 from repro.kernels.gas.gas import (EDGE_BLOCK, ROW_BLOCK, csr_steps,
                                    edge_major_fits,
                                    gas_gather_combine_pallas,
-                                   kernel_takes_gather,
+                                   kernel_takes_gather, kernel_takes_outer,
                                    gas_scatter_reschedule_pallas)
 from repro.kernels.gas.ref import gather_combine_ref, scatter_reschedule_ref
 
@@ -153,15 +153,20 @@ def gather_combine(
     *,
     block_active: Optional[jnp.ndarray] = None,
     interpret: Optional[bool] = None,
+    outer: bool = False,
 ) -> jnp.ndarray:
-    """Fused ``acc[v] = Σ_{u→v} w_e · feat[u]`` over ``edges`` → [N, D].
+    """Fused ``acc[v] = Σ_{u→v} w_e · feat[u]`` over ``edges`` → [N, D];
+    ``outer``: ``Σ_{u→v} w_e · feat[u] feat[u]ᵀ`` → [N, D²] (row major).
 
     ``interpret`` falsy (None/False) is the production dispatch: compiled
     kernel on TPU, oracle elsewhere.  ``interpret=True`` forces the kernel
     body through the Pallas interpreter on any backend (how tests validate
     it on CPU).  Either way a table the kernels do not take
     (``kernel_takes_gather``: an over-wide feature, or a scalar table too
-    large for VMEM) goes to the oracle.
+    large for VMEM) goes to the oracle.  An outer gather runs in the
+    row-block kernel from the ``D``-wide table where ``kernel_takes_outer``
+    (under the scope ``graphlab.outer``); elsewhere, and off the kernel
+    path, it gathers the ``[N, D²]`` table of outer products.
     """
     assert feat.ndim == 2, feat.shape
     e_pad = edges.senders.shape[0]
@@ -170,12 +175,23 @@ def gather_combine(
         if w.shape[0] != e_pad:
             w = jnp.pad(w, (0, e_pad - w.shape[0]))
 
+    if block_active is None:
+        block_active = jnp.ones((edges.n_row_blocks,), jnp.int32)
+    on_kernel = bool(interpret) or jax.default_backend() == "tpu"
+    n, d = feat.shape
+    if outer and on_kernel and kernel_takes_outer(n, d):
+        with jax.named_scope("graphlab.outer"), \
+                jax.named_scope("graphlab.gather"):
+            return gas_gather_combine_pallas(
+                feat, w, edges.senders, edges.receivers, edges.n_vertices,
+                edges.step_rb, edges.step_eb, block_active,
+                interpret=bool(interpret), outer=True)
+
     with jax.named_scope("graphlab.gather"):
-        if block_active is None:
-            block_active = jnp.ones((edges.n_row_blocks,), jnp.int32)
-        if (not interpret and jax.default_backend() != "tpu") or \
-                not kernel_takes_gather(feat.shape[0], feat.shape[1],
-                                        edges.n_vertices):
+        if outer:
+            feat = (feat[:, :, None] * feat[:, None, :]).reshape(n, d * d)
+        if not on_kernel or not kernel_takes_gather(
+                feat.shape[0], feat.shape[1], edges.n_vertices):
             return gather_combine_ref(
                 feat, w, edges.senders, edges.receivers, edges.n_vertices,
                 block_active)

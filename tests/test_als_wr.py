@@ -132,9 +132,9 @@ def table_residence(request, monkeypatch):
     seen = []
     kernel = gas._row_block_kernel
 
-    def spy(*, n_planes, resident):
-        seen.append((n_planes, resident))
-        return kernel(n_planes=n_planes, resident=resident)
+    def spy(*, n_planes, resident, outer=False):
+        seen.append((n_planes, resident, outer))
+        return kernel(n_planes=n_planes, resident=resident, outer=outer)
 
     monkeypatch.setattr(gas, "_row_block_kernel", spy)
     if request.param == "hbm":
@@ -147,13 +147,16 @@ def table_residence(request, monkeypatch):
 def test_row_block_kernel_at_d20(small, table_residence):
     """The Pallas row-block kernel, in interpret mode, at the cell's widths:
     the 400-wide ``xxt`` leaf (4 planes of 128 lanes) and the 20-wide
-    ``rx`` leaf (1 plane)."""
+    ``rx`` leaf (1 plane).  Where the 20-wide table stays resident the
+    kernel forms ``x xᵀ`` itself (``outer``); where it does not, the
+    400-wide table of outer products is gathered by row DMAs."""
     g, graph = small
     where, seen = table_residence
     got = engine_factors(graph, 2, gas_interpret=True)
     assert rel_err(got, numpy_als(g, 2)) <= REL
-    assert sorted(set(seen)) == [(1, where == "resident"),
-                                 (4, where == "resident")]
+    resident = where == "resident"
+    assert sorted(set(seen)) == [(1, resident, False),
+                                 (4, resident, resident)]
 
 
 def test_reg_I_ignores_the_counts():

@@ -287,6 +287,29 @@ class TestDistributedFused:
         rc, _ = _fixed_point(chrom, g, "rank")
         assert np.abs(rv - rc).max() <= TOL
 
+    @pytest.mark.parametrize("interpret", [None, True],
+                             ids=["oracle", "interpret"])
+    def test_dist_als_fused_matches_dense(self, cpu_mesh, interpret):
+        """ALS's fused declaration (its ``xxt`` leaf a
+        ``weighted_src_outer``) through the distributed engine's fused
+        local compute, on the materialised table (oracle) and formed in
+        the row-block kernel (interpret), against the dense gather."""
+        from repro.dist.engine import DistributedEngine
+        g, _ = make_als_graph(30, 35, 1000, d=4, seed=1)
+        prog = ALSProgram(d=4)
+        fused = DistributedEngine(prog, g, cpu_mesh, tolerance=1e-4,
+                                  gas_interpret=interpret)
+        dense = DistributedEngine(prog, g, cpu_mesh, tolerance=1e-4,
+                                  use_fused=False)
+        assert fused._use_fused and not dense._use_fused
+        text = fused._jit_step.lower(fused.init(), fused._tables).as_text(
+            debug_info=True)
+        assert ("graphlab.outer" in text) == bool(interpret)
+        sf, _ = fused.run(fused.init(), max_steps=40)
+        sd, _ = dense.run(dense.init(), max_steps=40)
+        assert np.abs(fused.vertex_data(sf)["factor"]
+                      - dense.vertex_data(sd)["factor"]).max() <= TOL
+
     def test_dist_dense_knob_matches_fused(self, cpu_mesh, pagerank_setup):
         """use_fused=False forces the seed dense shard_map body (A/B)."""
         from repro.dist.engine import DistributedEngine

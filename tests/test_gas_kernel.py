@@ -155,6 +155,131 @@ class TestGatherCombine:
 
 
 # ---------------------------------------------------------------------------
+# weighted_src_outer: Σ w·x xᵀ formed in the row-block kernel from the
+# d-wide table, and the dispatch to the materialised [N, d²] table
+# ---------------------------------------------------------------------------
+
+def _numpy_outer_truth(x, w, snd, recv, n, block_active=None):
+    """Independent ``Σ_{u→v} w_e x_u x_uᵀ`` in float64 → [n, d²]."""
+    d = x.shape[1]
+    acc = np.zeros((n, d, d))
+    xs = x[snd].astype(np.float64)
+    np.add.at(acc, recv, w[:, None, None] * xs[:, :, None] * xs[:, None, :])
+    if block_active is not None:
+        keep = np.repeat(np.asarray(block_active).astype(bool),
+                         ROW_BLOCK)[:n]
+        acc[~keep] = 0.0
+    return acc.reshape(n, d * d)
+
+
+def _outer_case(case, d, seed=0):
+    """(x, w, snd, recv, n, active mask) of one outer-gather case: a hub
+    whose in-edges span several edge blocks, general or 0/1 weights,
+    partly or wholly inactive row blocks, or no edges."""
+    rng = np.random.default_rng(seed + d)
+    n = 300
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    if case == "empty":
+        snd = recv = np.zeros(0, np.int32)
+    else:
+        hub = np.full(3 * EDGE_BLOCK, 5)     # one receiver, 3+ edge blocks
+        rest = np.minimum((rng.pareto(1.2, 1500) * 3).astype(np.int64),
+                          n - 1)
+        recv = np.sort(np.concatenate([hub, rest])).astype(np.int32)
+        snd = rng.integers(0, n, recv.size).astype(np.int32)
+    if case == "general":
+        w = rng.normal(size=snd.size)
+    else:                                    # ALS's train flags
+        w = (rng.random(snd.size) < 0.8).astype(np.float64)
+    frac = {"partial": 0.3, "inactive": 0.0}.get(case, 1.0)
+    mask = rng.random(n) < frac
+    if case == "partial":
+        mask[5] = True                       # the hub's row block stays on
+    return x, w.astype(np.float32), snd, recv, n, mask
+
+
+class TestOuterGather:
+    @pytest.mark.parametrize("d", [1, 4, 20, 24])
+    @pytest.mark.parametrize("case", ["general", "binary", "partial",
+                                      "inactive", "empty"])
+    def test_kernel_matches_numpy(self, case, d):
+        """The row-block kernel's outer path (interpret mode) against a
+        float64 NumPy ``Σ w·x xᵀ``; with 0/1 weights it agrees with
+        today's gather of the materialised table: bit for bit where that
+        is the row-block kernel (d > 1), to float32 rounding where it is
+        the edge-major kernel, which sums in another order (d = 1)."""
+        from repro.kernels.gas.gas import kernel_takes_outer
+        x, w, snd, recv, n, mask = _outer_case(case, d)
+        assert kernel_takes_outer(n, d)
+        edges = EdgeSet.build(snd, recv, n)
+        blk = active_row_blocks(jnp.asarray(mask))
+        got = np.asarray(gather_combine(
+            jnp.asarray(x), jnp.asarray(w), edges, block_active=blk,
+            interpret=True, outer=True))
+        truth = _numpy_outer_truth(x, w, snd, recv, n, np.asarray(blk))
+        assert got.shape == (n, d * d)
+        if case in ("inactive", "empty"):
+            assert not got.any()
+            return
+        scale = np.abs(truth).max()
+        assert np.abs(got - truth).max() / scale < 2e-5
+        if case != "general":
+            table = (x[:, :, None] * x[:, None, :]).reshape(n, d * d)
+            today = np.asarray(gather_combine(
+                jnp.asarray(table), jnp.asarray(w), edges, block_active=blk,
+                interpret=True))
+            if d > 1:
+                np.testing.assert_array_equal(got, today)
+            else:
+                np.testing.assert_allclose(got, today, rtol=1e-6)
+
+    @pytest.mark.parametrize("why", ["d2_too_wide", "not_resident"])
+    def test_fallback_gathers_the_table(self, why, monkeypatch):
+        """Where the kernel cannot form the messages itself — ``d²`` wider
+        than ``MAX_FEAT`` (d = 40), or a d-wide table too large to stay
+        resident — the outer gather is the gather of the materialised
+        ``[N, d²]`` table, bit for bit, and takes no ``graphlab.outer``."""
+        from repro.kernels.gas import gas
+        d = 40 if why == "d2_too_wide" else 20
+        x, w, snd, recv, n, mask = _outer_case("binary", d)
+        if why == "not_resident":
+            monkeypatch.setattr(gas, "RESIDENT_BYTES",
+                                n * gas.LANES * 4 - 1)
+        assert not gas.kernel_takes_outer(n, d)
+        jax.clear_caches()
+        edges = EdgeSet.build(snd, recv, n)
+        blk = active_row_blocks(jnp.asarray(mask))
+        table = jnp.asarray((x[:, :, None] * x[:, None, :]).reshape(n, -1))
+
+        def outer(x, w):
+            return gather_combine(x, w, edges, block_active=blk,
+                                  interpret=True, outer=True)
+
+        args = (jnp.asarray(x), jnp.asarray(w))
+        got = np.asarray(outer(*args))
+        want = np.asarray(gather_combine(table, args[1], edges,
+                                         block_active=blk, interpret=True))
+        jax.clear_caches()
+        np.testing.assert_array_equal(got, want)
+        assert "graphlab.outer" not in jax.jit(outer).lower(
+            *args).as_text(debug_info=True)
+        truth = _numpy_outer_truth(x, w, snd, recv, n, np.asarray(blk))
+        assert np.abs(got - truth).max() / np.abs(truth).max() < 2e-5
+
+    def test_outer_leaf_needs_a_weight(self):
+        from repro.core.update import (FusedGather, VertexProgram,
+                                       fused_gather_leaves)
+
+        class NoWeight(VertexProgram):
+            def fused_gather(self):
+                return FusedGather("weighted_src_outer",
+                                   feature=lambda v: v["x"])
+
+        with pytest.raises(ValueError, match="weighted_src_outer"):
+            fused_gather_leaves(NoWeight())
+
+
+# ---------------------------------------------------------------------------
 # jaxpr inspection: the fused step materializes no [E, D] intermediate
 # ---------------------------------------------------------------------------
 
@@ -177,6 +302,31 @@ def _collect_shapes(obj, out):
             for sub in (p if isinstance(p, (tuple, list)) else (p,)):
                 if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
                     _collect_shapes(sub, out)
+
+
+def _named_eqns(fn, args):
+    """(primitive, jit name, output shape, dtype, name stack) of every
+    eqn output of ``fn``'s jaxpr, recursing into closed jaxprs; a name
+    stack carries its enclosing eqns' scopes."""
+    out = []
+
+    def walk(obj, prefix):
+        jaxpr = getattr(obj, "jaxpr", obj)
+        for eqn in getattr(jaxpr, "eqns", ()):
+            names = "/".join(p for p in (
+                prefix, str(eqn.source_info.name_stack)) if p)
+            for v in eqn.outvars:
+                aval = getattr(v, "aval", None)
+                if aval is not None and hasattr(aval, "shape"):
+                    out.append((eqn.primitive.name, eqn.params.get("name"),
+                                tuple(aval.shape), aval.dtype, names))
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                    if hasattr(sub, "eqns") or hasattr(sub, "jaxpr"):
+                        walk(sub, names)
+
+    walk(jax.make_jaxpr(fn)(*args), "")
+    return out
 
 
 def _edge_row_intermediates(fn, args, edge_dims):
@@ -233,6 +383,45 @@ class TestNoEdgeDimIntermediates:
         dstate = dense.init(graph)
         dense_bad = _edge_row_intermediates(dense._step, (dstate,), dims)
         assert any(len(s) == 3 for s in dense_bad), dense_bad
+
+    @staticmethod
+    def _outer_tables(eng, graph):
+        """Float ``[N, d, d]`` / ``[N, d²]`` values the step computes
+        outside the apply, other than the gather's own result (the kernel
+        entry's output, its slice and its reshape): the x xᵀ vertex table
+        a materialised gather would build."""
+        n, d = graph.n_vertices, 4
+        return [(prim, shape) for prim, _, shape, dtype, names
+                in _named_eqns(eng._step, (eng.init(graph),))
+                if shape in ((n, d, d), (n, d * d))
+                and jnp.issubdtype(dtype, jnp.floating)
+                and "graphlab.apply" not in names
+                and prim not in ("jit", "slice", "reshape")]
+
+    def test_fused_als_step_builds_no_outer_table(self):
+        """On the kernel path the ``xxt`` leaf is formed in the kernel from
+        the d-wide factor table, under ``graphlab.outer``; the ``rx``
+        leaf's gather is not."""
+        eng, graph = self._engines(self._als, use_fused=True,
+                                   gas_interpret=True)
+        assert not self._outer_tables(eng, graph)
+        n, d = graph.n_vertices, 4
+        gathers = {shape: "graphlab.outer" in names
+                   for _, name, shape, _, names
+                   in _named_eqns(eng._step, (eng.init(graph),))
+                   if name == "gas_gather_combine_pallas"}
+        assert gathers == {(n, d * d): True, (n, d): False}
+        # sanity on the instrument: off the kernel path the step gathers
+        # the materialised table, and the check finds it
+        oracle, _ = self._engines(self._als, use_fused=True)
+        assert ("mul", (n, d, d)) in self._outer_tables(oracle, graph)
+
+    def test_fused_pagerank_step_takes_no_outer_path(self):
+        eng, graph = self._engines(self._pagerank, use_fused=True,
+                                   gas_interpret=True)
+        names = [e[4] for e in _named_eqns(eng._step, (eng.init(graph),))]
+        assert any("graphlab.gather" in n for n in names)
+        assert not any("graphlab.outer" in n for n in names)
 
 
 # ---------------------------------------------------------------------------

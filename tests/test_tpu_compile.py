@@ -6,8 +6,9 @@ SMEM and VMEM budgets.  These tests compile each engine kernel for one
 chip of a described (not attached) ``v5e:2x2`` topology at deployment
 sizes: 2^20 vertices and 16M edges, for scalar tables (PageRank, every
 scheduler scatter) and for ALS's x·xᵀ table at d = 20 (400 wide, 512
-padded), the Netflix ALS-WR cell's two gathers at its own sizes, and the
-segment sum at the widths its dispatch rule takes.  The
+padded), the Netflix ALS-WR cell's gathers at its own sizes (x·xᵀ also
+formed in the kernel from the 20-wide table), and the segment sum at the
+widths its dispatch rule takes.  The
 16M-edge cases guard the SMEM budget: a kernel that scalar-prefetches an
 ``[E]`` array is refused here.  Nothing runs.
 """
@@ -73,11 +74,11 @@ def test_gather_combine_compiles(one_chip, width):
 
 @pytest.mark.parametrize("width", [ALS_XXT, 20])
 def test_als_cell_gathers_compile(one_chip, width):
-    """The two fused gathers of the Netflix ALS-WR cell at its sizes:
-    77,794 vertices and a color-step padded to 2^24 edges.  Its 400-wide
-    x·xᵀ table (159 MB) is staged from HBM; its 20-wide table (39.8 MB)
-    stays resident in VMEM, the largest resident table the cell asks
-    for."""
+    """The row-block gathers of the Netflix ALS-WR cell's widths at its
+    sizes: 77,794 vertices and a color-step padded to 2^24 edges.  A
+    400-wide x·xᵀ table (159 MB, the outer gather's fallback) is staged
+    from HBM; the 20-wide table (39.8 MB) stays resident in VMEM, the
+    largest resident table the cell asks for."""
     n_rows = 77_794
     n_steps = N_EDGES // gas.EDGE_BLOCK + -(-n_rows // gas.ROW_BLOCK)
     planes = -(-width // gas.LANES)
@@ -91,6 +92,25 @@ def test_als_cell_gathers_compile(one_chip, width):
 
     shapes = _edge_shapes()[:3] + [((n_steps,), jnp.int32)] * 2
     text = _compiled_text(fn, one_chip, ((n_rows, width), jnp.float32),
+                          *shapes,
+                          ((-(-n_rows // gas.ROW_BLOCK),), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_als_cell_outer_gather_compiles(one_chip):
+    """The cell's ``xxt`` gather as it runs: x·xᵀ formed in the row-block
+    kernel from the resident 20-wide factor table, at the cell's sizes."""
+    n_rows, d = 77_794, 20
+    assert gas.kernel_takes_outer(n_rows, d)
+    n_steps = N_EDGES // gas.EDGE_BLOCK + -(-n_rows // gas.ROW_BLOCK)
+
+    def fn(feat, w, snd, rcv, step_rb, step_eb, act):
+        return gas.gas_gather_combine_pallas(feat, w, snd, rcv, n_rows,
+                                             step_rb, step_eb, act,
+                                             outer=True)
+
+    shapes = _edge_shapes()[:3] + [((n_steps,), jnp.int32)] * 2
+    text = _compiled_text(fn, one_chip, ((n_rows, d), jnp.float32),
                           *shapes,
                           ((-(-n_rows // gas.ROW_BLOCK),), jnp.int32))
     assert "tpu_custom_call" in text
