@@ -24,6 +24,7 @@ from __future__ import annotations
 
 from typing import Optional, Sequence
 
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.coloring import coloring_for, verify_coloring
@@ -95,6 +96,9 @@ class ChromaticEngine(Engine):
         exactly zero.  Σ_c E_c = E for each, per sweep.  Each set is padded
         to a power-of-two block count, and runs of consecutive colors of
         one size class share a grid length, so they stack into one loop.
+        A color's gather edges sit in the full edge arrays in another
+        order: each run's perms into them (``[colors, E_pad]``, padded
+        with 0) come back beside its sets, for ``prepare_weights``.
 
         Streaming mode has none: the dynamic-tables path streams the full
         capacity edge set each phase (the color mask gates the
@@ -112,17 +116,20 @@ class ChromaticEngine(Engine):
                   for kind, idx in subsets.items()}
         groups = color_runs(blocks)
 
-        stacked = []
+        stacked, perms = [], []
         for first, count in groups:
-            sets = [{} for _ in range(count)]
+            run = range(first, first + count)
+            sets = [{} for _ in run]
             for kind, idx in subsets.items():
                 nb = blocks[kind][first]
-                n_steps = max(steps_bound(idx[c].size, n)
-                              for c in range(first, first + count))
-                for j, c in enumerate(range(first, first + count)):
+                n_steps = max(steps_bound(idx[c].size, n) for c in run)
+                for j, c in enumerate(run):
                     sets[j][kind] = EdgeSet.build(
                         st.senders[idx[c]], st.receivers[idx[c]], n,
-                        perm=idx[c] if kind == "gather" else None,
                         min_blocks=nb, n_steps=n_steps)
             stacked.append(stack_edge_sets(sets))
-        return groups, stacked
+            idx = subsets["gather"]
+            e_pad = sets[0]["gather"].senders.shape[0]
+            perms.append(jnp.asarray(np.stack(
+                [np.pad(idx[c], (0, e_pad - idx[c].size)) for c in run])))
+        return groups, stacked, perms

@@ -25,12 +25,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.graph import DataGraph, segment_combine
-from repro.core.scheduler import Scheduler, SweepScheduler, reschedule_prio
+from repro.core.scheduler import Scheduler, SweepScheduler
 from repro.core.sync_op import SyncOp, run_syncs
 from repro.core.update import (EdgeCtx, VertexProgram, edge_ctx,
                                fused_edge_weight, fused_gather_leaves,
                                masked_update, supports_fused_gather)
-from repro.kernels.gas.gas import EDGE_BLOCK, ROW_BLOCK, csr_steps
+from repro.kernels.gas.gas import ROW_BLOCK
 from repro.kernels.gas.ops import (EdgeSet, ScatterCtx, active_row_blocks,
                                    gather_combine, stack_edge_sets)
 from repro.obs.timeline import span
@@ -56,7 +56,7 @@ class EngineState:
     globals_: Pytree           # sync-op outputs readable by update fns
     sched: Pytree = ()         # scheduler-private state (() if stateless)
     # fused-gather edge weights prepared from ``graph.edge_data`` in each
-    # color-step's edge order (``Engine.prepare_weights``); () if none
+    # phase's gather-set order (``Engine.prepare_weights``); () if none
     gas_weights: Pytree = ()
 
     def replace(self, **kw) -> "EngineState":
@@ -111,7 +111,8 @@ def apply_phase(
     edges touched).  Passing ``edges`` (a prepared ``EdgeSet``) routes the
     gather⊕combine through the fused GAS kernel with active-block skipping
     (DESIGN.md §3.5), with ``weights`` as ``fused_apply_phase`` takes them;
-    the dense path gathers all E edges regardless of mask.
+    the dense path gathers all E edges regardless of mask.  The streaming
+    engines' dense phase is ``stream_apply_phase``.
 
     ``residual_dtype`` is the scheduler's priority precision: f32 by
     default, f64 opt-in for tolerance regimes below the f32 residual floor
@@ -150,15 +151,6 @@ def apply_phase(
     with jax.named_scope("graphlab.apply"):
         residual = jnp.where(mask, residual.astype(residual_dtype), 0.0)
     return graph, residual, jnp.asarray(st.n_edges, jnp.int32)
-
-
-def _source_degrees(st, leaves) -> Optional[jnp.ndarray]:
-    """Out-degree of each full-edge source, or None: only
-    degree_normalized_src leaves consult it, so don't gather/ship an [E]
-    array otherwise."""
-    if any(leaf.kind == "degree_normalized_src" for leaf in leaves):
-        return jnp.asarray(st.out_degree[st.senders])
-    return None
 
 
 def leaf_weights(leaves, edge_data: Pytree, n_edges: int,
@@ -205,34 +197,26 @@ def fused_apply_phase(
     mask: jnp.ndarray,
     glob: Pytree,
     edges: EdgeSet,
+    weights: Sequence[jnp.ndarray],
     *,
-    weights: Optional[Sequence[jnp.ndarray]] = None,
     interpret: Optional[bool] = None,
     residual_dtype=jnp.float32,
 ) -> Tuple[DataGraph, jnp.ndarray, jnp.ndarray]:
     """The fused GAS path: one kernel per declared gather leaf, no edge_ctx,
     no [E, D] message materialization, inactive row blocks skipped.
 
-    Per leaf: the per-vertex feature table ``[N, ...]`` and the per-edge
-    scalar weight are formed outside the kernel (both sub-[E, D]), the
-    kernel streams the ``edges`` subset and accumulates in VMEM.  The
-    weights are evaluated on the full edge data, or, for a subset of the
-    edges (a color's), come prepared in ``weights``: one ``[E_pad]`` array
-    per leaf in the subset's edge order (``Engine.prepare_weights``).
+    Per leaf: the per-vertex feature table ``[N, ...]`` is formed outside
+    the kernel (sub-[E, D]), the kernel streams the ``edges`` subset and
+    accumulates in VMEM.  ``weights`` come prepared: one ``[E_pad]`` row
+    per leaf in ``edges``' order (``Engine.prepare_weights``).
     Rows outside active blocks come back as zeros; they belong to
     unscheduled vertices whose apply output is discarded by
     ``masked_update`` and whose residual is masked below, so the fixed
     point matches the dense path.
     """
-    st = graph.structure
     leaves, treedef = fused_gather_leaves(program)
-    assert weights is not None or edges.perm is None, \
-        "a subset of the edges takes its weights prepared"
     with jax.named_scope("graphlab.gather"):
         block_active = active_row_blocks(mask)
-    if weights is None:
-        weights = leaf_weights(leaves, graph.edge_data, st.n_edges,
-                               _source_degrees(st, leaves))
     acc = gather_leaves(leaves, treedef, graph.vertex_data, weights, edges,
                         block_active=block_active, interpret=interpret)
 
@@ -254,24 +238,20 @@ def stream_apply_phase(
     glob: Pytree,
     tables: Dict[str, jnp.ndarray],
     *,
-    fused_meta=None,
-    interpret: Optional[bool] = None,
     tolerance: float = 1e-3,
     residual_dtype=jnp.float32,
 ) -> Tuple[DataGraph, jnp.ndarray, jnp.ndarray, Optional[jnp.ndarray]]:
-    """``apply_phase`` over a *dynamic* edge structure (DESIGN.md §3.11).
+    """The dense ``apply_phase`` over a *dynamic* edge structure
+    (DESIGN.md §3.11): edge-writing and non-fusable programs.
 
     The streaming engines trade the baked-in structure constants for the
     ``tables`` dict of traced arrays {senders, receivers, edge_mask,
     rev_idx, in_deg, out_deg, block_counts}: a delta batch patches the
     table *values* (same shapes) and the jitted step never retraces.
     Capacity (slack) edge rows carry ``edge_mask == False`` and are routed
-    to a dropped segment / zero weight, so they contribute exactly nothing.
-
-    ``fused_meta`` (from ``Engine._build_stream_fused``) carries the static
-    grid schedule of the capacity layout — receivers never move (slot
-    reservation per receiver), so the GAS kernel's steps are computed once
-    and only the senders/weights stream through the trace.
+    to a dropped segment, so they contribute exactly nothing.  A fused
+    streaming phase is ``fused_apply_phase`` over the capacity edge set
+    (``Engine._stream_sets``).
 
     Returns ``(graph, residual, edges_touched, prio_bump)``.  For
     edge-writing programs, ``prio_bump`` carries the *message residual*
@@ -285,50 +265,28 @@ def stream_apply_phase(
     n = st.n_vertices
     senders, receivers = tables["senders"], tables["receivers"]
     emask = tables["edge_mask"]
-    e_cap = senders.shape[0]
+    rp = jnp.maximum(tables["rev_idx"], 0)
+    has_rev = tables["rev_idx"] >= 0
 
-    if fused_meta is not None:
-        leaves, treedef, step_rb, step_eb, e_pad = fused_meta
-        with jax.named_scope("graphlab.gather"):
-            block_active = active_row_blocks(mask)
-        snd = jnp.pad(senders, (0, e_pad - e_cap))
-        rcv = jnp.pad(receivers, (0, e_pad - e_cap),
-                      constant_values=n + ROW_BLOCK)
-        es = EdgeSet(n_vertices=n, senders=snd,
-                     receivers=rcv, step_rb=step_rb, step_eb=step_eb)
-        src_deg_e = tables["out_deg"][senders] if any(
-            leaf.kind == "degree_normalized_src" for leaf in leaves) else None
-        weights = leaf_weights(leaves, graph.edge_data, e_cap, src_deg_e,
-                               edge_mask=emask)
-        acc = gather_leaves(leaves, treedef, graph.vertex_data, weights, es,
-                            block_active=block_active, interpret=interpret)
-        with jax.named_scope("graphlab.gather"):
-            edges_touched = jnp.sum(
-                jnp.where(block_active > 0, tables["block_counts"], 0)
-            ).astype(jnp.int32)
-    else:
-        rp = jnp.maximum(tables["rev_idx"], 0)
-        has_rev = tables["rev_idx"] >= 0
+    def _rev(x):
+        y = x[rp]
+        m = has_rev.reshape((-1,) + (1,) * (y.ndim - 1))
+        return jnp.where(m, y, jnp.zeros_like(y))
 
-        def _rev(x):
-            y = x[rp]
-            m = has_rev.reshape((-1,) + (1,) * (y.ndim - 1))
-            return jnp.where(m, y, jnp.zeros_like(y))
-
-        with jax.named_scope("graphlab.gather"):
-            ctx = EdgeCtx(
-                edata=graph.edge_data,
-                rev_edata=jax.tree.map(_rev, graph.edge_data),
-                src=jax.tree.map(lambda x: x[senders], graph.vertex_data),
-                dst=jax.tree.map(lambda x: x[receivers], graph.vertex_data),
-                src_deg=tables["out_deg"][senders],
-                dst_deg=tables["in_deg"][receivers])
-            msgs = program.gather(ctx)
-            recv_idx = jnp.where(emask, receivers, n)
-            acc = segment_combine(msgs, recv_idx, n + 1, program.combiner,
-                                  indices_are_sorted=False)
-            acc = jax.tree.map(lambda a: a[:n], acc)
-            edges_touched = jnp.sum(emask.astype(jnp.int32))
+    with jax.named_scope("graphlab.gather"):
+        ctx = EdgeCtx(
+            edata=graph.edge_data,
+            rev_edata=jax.tree.map(_rev, graph.edge_data),
+            src=jax.tree.map(lambda x: x[senders], graph.vertex_data),
+            dst=jax.tree.map(lambda x: x[receivers], graph.vertex_data),
+            src_deg=tables["out_deg"][senders],
+            dst_deg=tables["in_deg"][receivers])
+        msgs = program.gather(ctx)
+        recv_idx = jnp.where(emask, receivers, n)
+        acc = segment_combine(msgs, recv_idx, n + 1, program.combiner,
+                              indices_are_sorted=False)
+        acc = jax.tree.map(lambda a: a[:n], acc)
+        edges_touched = jnp.sum(emask.astype(jnp.int32))
 
     with jax.named_scope("graphlab.apply"):
         new_v, residual = program.apply(graph.vertex_data, acc, glob)
@@ -337,7 +295,6 @@ def stream_apply_phase(
 
     prio_bump = None
     if program.has_edge_out:
-        assert fused_meta is None, "edge_out programs keep the dense path"
         new_src = jax.tree.map(lambda x: x[senders], vdata)
         src_acc = jax.tree.map(lambda a: a[senders], acc)
         ctx2 = ctx._replace(
@@ -378,11 +335,6 @@ def edge_residual_bump(old_e: Pytree, new_e: Pytree, wmask: jnp.ndarray,
     recv_idx = jnp.where(emask, receivers, n)
     return jnp.maximum(jax.ops.segment_max(
         jnp.where(wmask, delta, 0.0), recv_idx, n + 1), 0.0)[:n]
-
-
-# Back-compat name: the reschedule rule now lives in the scheduler
-# subsystem (core/scheduler.py, DESIGN.md §3.8).
-schedule_phase = reschedule_prio
 
 
 class Engine:
@@ -436,8 +388,11 @@ class Engine:
         self.scheduler = (scheduler if scheduler is not None
                           else self._make_scheduler())
         self._tables: Optional[Dict[str, jnp.ndarray]] = None
-        self._stream_fused_meta = None
         self._stream_colors: Optional[np.ndarray] = None
+        # the fused streaming phases' capacity edge set: slot reservation
+        # per receiver freezes its receivers, so its kernel grid is fixed
+        # here, and ``_stream_sets`` fills in the live tables every step
+        self._stream_edges: Optional[EdgeSet] = None
         if stream_tables is not None:
             if not isinstance(self.scheduler, SweepScheduler):
                 raise UnsupportedStreamingError(
@@ -447,7 +402,9 @@ class Engine:
             self._stream_colors = np.asarray(self.scheduler.colors, np.int32)
             self.set_stream_tables(stream_tables)
             if self.use_fused:
-                self._stream_fused_meta = self._build_stream_fused()
+                self._stream_edges = EdgeSet.build(
+                    stream_tables["senders"], stream_tables["receivers"],
+                    self.structure.n_vertices)
         # Telemetry (DESIGN §3.15): pure host-side config — nothing below
         # reads it while building ``_step``, so the jaxpr is byte-identical
         # with obs on/off (tests/test_obs.py asserts it).
@@ -462,19 +419,15 @@ class Engine:
         # per color-step out of the coloring.
         static = stream_tables is None
         self._phase_groups, gas = ([], None)
-        # per phase group, the gather set's map from its edges into the
-        # full edge arrays (None where it gathers over all of them): the
-        # fused weights of a color's edges are gathered through it once
-        # per edge data (``prepare_weights``), so the step carries no perm
-        self._gas_perms: list = []
+        # per phase group (streaming: the capacity set), the perms of its
+        # gather sets into the full edge arrays, None where it gathers over
+        # all of them: ``prepare_weights`` lays the weights out by them
+        self._gas_perms: list = ([] if self._stream_edges is None
+                                 else [None])
         if self.use_fused and static:
             with span("graphlab.edge_sets"):
-                self._phase_groups, gas = self._phase_edge_sets()
-            self._gas_perms = [sets["gather"].perm for sets in gas]
-            gas = [dict(sets, gather=dataclasses.replace(sets["gather"],
-                                                         perm=None))
-                   for sets in gas]
-        self._prepares_weights = any(p is not None for p in self._gas_perms)
+                self._phase_groups, gas, self._gas_perms = \
+                    self._phase_edge_sets()
         self._jit_weights = jax.jit(self._gas_weights)
         self._consts = {
             "gas": gas,
@@ -495,7 +448,8 @@ class Engine:
         treedef/shapes/dtypes never change between ``regrow()``s, so the
         jitted step's cache entry keeps hitting.  The live coloring rides
         along as a table so incremental color repair (DESIGN.md §3.12)
-        never retraces either."""
+        never retraces either.  A state's prepared fused weights were made
+        from the old tables: the caller drops them (``gas_weights=()``)."""
         self._tables = {k: jnp.asarray(v) for k, v in tables.items()}
         if self._stream_colors is not None:
             self._tables["colors"] = jnp.asarray(self._stream_colors)
@@ -506,96 +460,102 @@ class Engine:
         if self._tables is not None:
             self._tables["colors"] = jnp.asarray(self._stream_colors)
 
-    def _build_stream_fused(self):
-        """Static GAS metadata of the capacity layout: slot reservation per
-        receiver keeps the receiver array frozen, so the kernel grid is
-        computed once, here."""
-        leaves, treedef = fused_gather_leaves(self.program)
-        st = self.structure
-        recv = np.asarray(self._tables["receivers"])
-        e_cap = recv.shape[0]
-        e_pad = max(-(-e_cap // EDGE_BLOCK), 1) * EDGE_BLOCK
-        pad_r = np.int32(st.n_vertices + ROW_BLOCK)
-        recv_p = np.pad(recv, (0, e_pad - e_cap), constant_values=pad_r)
-        step_rb, step_eb = csr_steps(recv_p, st.n_vertices)
-        return (leaves, treedef, jnp.asarray(step_rb), jnp.asarray(step_eb),
-                int(e_pad))
-
-    def _phase_edge_sets(self) -> Tuple[List[Tuple[int, int]], list]:
+    def _phase_edge_sets(self) -> Tuple[List[Tuple[int, int]], list, list]:
         """Prepared EdgeSets of the fused path, per phase: ``gather`` the
         edges the phase gathers over, ``scatter`` the edges its fused
         reschedule deposits along (every out-edge of a vertex the phase
-        may execute).  Returns ``(groups, stacked)``: ``groups`` lists
-        ``(first phase, phase count)`` runs of consecutive phases whose
-        sets share shapes, ``stacked`` the run's sets stacked on a leading
-        axis — one entry per phase, or a single entry the run's phases
-        share.  The base engine shares the full structure across all its
-        phases; the chromatic engine restricts each to its color."""
+        may execute).  Returns ``(groups, stacked, perms)``: ``groups``
+        lists ``(first phase, phase count)`` runs of consecutive phases
+        whose sets share shapes, ``stacked`` the run's sets stacked on a
+        leading axis — one entry per phase, or a single entry the run's
+        phases share — and ``perms`` the run's gather perms into the full
+        edge arrays, None where its sets gather over every edge.  The base
+        engine shares the full structure across all its phases; the
+        chromatic engine restricts each to its color."""
         st = self.structure
         full = EdgeSet.build(st.senders, st.receivers, st.n_vertices)
         return ([(0, self.scheduler.num_phases)],
-                [stack_edge_sets([{"gather": full, "scatter": full}])])
+                [stack_edge_sets([{"gather": full, "scatter": full}])],
+                [None])
 
-    def _gas_weights(self, edge_data, perms, src_deg):
+    def _gas_weights(self, edge_data, perms, src_deg, edge_mask):
         """Every fused leaf's edge weights, evaluated on the full edge data
-        and gathered into each color-step's edge order: per phase group,
-        per leaf, the group's ``[phases, E_pad]`` rows laid end to end in
-        one f32 vector (() for a group that gathers over all edges).  Flat,
-        because the TPU tiles a 2-D array's rows in eights: a group of one
-        phase would take eight times its bytes."""
+        (zero where ``edge_mask`` is False) and laid out for each phase
+        group's gather sets: per group, per leaf, the group's ``[phases,
+        E_pad]`` rows gathered through its perms and laid end to end in
+        one f32 vector, or, for a group that gathers over every edge, the
+        weights padded to its set's ``E_pad``.  Flat, because the TPU tiles
+        a 2-D array's rows in eights: a group of one phase would take eight
+        times its bytes."""
         leaves, _ = fused_gather_leaves(self.program)
         full = leaf_weights(leaves, edge_data, self.structure.n_edges,
-                            src_deg)
-        return tuple(() if perm is None else
-                     tuple(w[perm].reshape(-1) for w in full)
-                     for perm in perms)
+                            src_deg, edge_mask)
+        sets = ([self._stream_edges] if self._stream_edges is not None
+                else [g["gather"] for g in self._consts["gas"]])
+        rows = []
+        for perm, es in zip(perms, sets):
+            e_pad = es.senders.shape[-1]
+            rows.append(tuple(
+                jnp.pad(w, (0, e_pad - w.shape[0])) if perm is None
+                else w[perm].reshape(-1) for w in full))
+        return tuple(rows)
 
     def prepare_weights(self, state: EngineState) -> EngineState:
         """``state`` with ``gas_weights`` prepared from its edge data, where
-        the engine's fused phases gather over a subset of the edges and the
-        state has none.  The fused path runs only programs that never
-        write edges, so the weights hold until ``graph.edge_data`` is
-        replaced (``EngineState.replace`` then drops them)."""
-        if not self._prepares_weights or state.gas_weights:
+        the engine runs the fused path and the state has none: the one
+        place the local engines evaluate the fused edge weights.  The
+        fused path runs only programs that never write edges, so the
+        weights hold until ``graph.edge_data`` is replaced
+        (``EngineState.replace`` then drops them).  In streaming mode they
+        are evaluated on the capacity edge data, masked by the tables'
+        ``edge_mask``, with source degrees from their ``out_deg``: they
+        hold until the tables change too, and ``stream.ingest.apply_delta``,
+        which installs new tables, drops them."""
+        if not self.use_fused or state.gas_weights:
             return state
-        src_deg = _source_degrees(self.structure,
-                                  fused_gather_leaves(self.program)[0])
+        tb = self._tables or {"senders": self.structure.senders,
+                              "out_deg": self.structure.out_degree}
+        src_deg = None
+        if any(leaf.kind == "degree_normalized_src"
+               for leaf in fused_gather_leaves(self.program)[0]):
+            src_deg = jnp.asarray(tb["out_deg"])[jnp.asarray(tb["senders"])]
         with span("graphlab.weights"):
             weights = jax.block_until_ready(self._jit_weights(
-                state.graph.edge_data, self._gas_perms, src_deg))
+                state.graph.edge_data, self._gas_perms, src_deg,
+                tb.get("edge_mask")))
         return state.replace(gas_weights=weights)
+
+    def _stream_sets(self, tables) -> Optional[dict]:
+        """The fused streaming phases' edge sets: the capacity set with
+        the tables' senders, receivers and live block counts (None off
+        the fused path).  Gather and scatter share it."""
+        es = self._stream_edges
+        if es is None:
+            return None
+        pad = es.senders.shape[0] - tables["senders"].shape[0]
+        es = dataclasses.replace(
+            es, senders=jnp.pad(tables["senders"], (0, pad)),
+            receivers=jnp.pad(tables["receivers"], (0, pad),
+                              constant_values=es.n_vertices + ROW_BLOCK),
+            block_counts=tables["block_counts"])
+        return {"gather": es, "scatter": es}
 
     def _scatter_ctx(self, tables, sets) -> Optional[ScatterCtx]:
         """ScatterCtx for the fused reschedule (DESIGN.md §3.14), or None
         to keep the dense scatter.  Gated on f32 priorities: the f64
         residual opt-in keeps the dense path rather than silently
         downcasting through the f32 kernel."""
-        if not (self.use_fused and self.program.schedule_neighbors):
+        if not (sets and self.program.schedule_neighbors):
             return None
         if self.residual_dtype != jnp.float32:
             return None
-        if tables is None:
-            return ScatterCtx(edges=sets["scatter"],
-                              interpret=self.gas_interpret)
-        if self._stream_fused_meta is None:
-            return None
-        # dynamic structure: the capacity EdgeSet streams through the
-        # trace (values change, shapes never do); slack slots carry real
-        # receiver ids, so the live edge mask must ride as the weights —
-        # otherwise a reserved self-loop would bump its own receiver
-        _, _, step_rb, step_eb, e_pad = self._stream_fused_meta
-        n = self.structure.n_vertices
-        e_cap = tables["senders"].shape[0]
-        es = EdgeSet(
-            n_vertices=n,
-            senders=jnp.pad(tables["senders"], (0, e_pad - e_cap)),
-            receivers=jnp.pad(tables["receivers"], (0, e_pad - e_cap),
-                              constant_values=n + ROW_BLOCK),
-            step_rb=step_rb, step_eb=step_eb)
-        w = jnp.pad(tables["edge_mask"].astype(jnp.float32),
-                    (0, e_pad - e_cap))
-        return ScatterCtx(edges=es, weights=w,
-                          interpret=self.gas_interpret)
+        # streaming: slack slots carry real receiver ids, so the live edge
+        # mask must ride as the weights — otherwise a reserved self-loop
+        # would bump its own receiver
+        return ScatterCtx(
+            edges=sets["scatter"],
+            weights=None if tables is None else tables["edge_mask"],
+            interpret=self.gas_interpret)
 
     def _step(self, state: EngineState, tables=None,
               consts=None) -> EngineState:
@@ -608,7 +568,7 @@ class Engine:
         prev_vdata = state.graph.vertex_data
         glob = state.globals_
         gas_weights = state.gas_weights
-        if self._prepares_weights and not gas_weights:
+        if self.use_fused and not gas_weights:
             raise ValueError("the state carries no prepared edge weights: "
                              "pass it through Engine.prepare_weights")
 
@@ -617,7 +577,8 @@ class Engine:
             with jax.named_scope("graphlab.select"):
                 mask, sched = self.scheduler.select(sched, prio, phase,
                                                     tables=select_tables)
-            if tables is None:
+            bump = None
+            if tables is None or sets:
                 graph, residual, et = apply_phase(
                     self.program, graph, mask, glob,
                     edges=sets["gather"] if sets else None,
@@ -626,14 +587,13 @@ class Engine:
             else:
                 graph, residual, et, bump = stream_apply_phase(
                     self.program, graph, mask, glob, tables,
-                    fused_meta=self._stream_fused_meta,
-                    interpret=self.gas_interpret, tolerance=self.tolerance,
+                    tolerance=self.tolerance,
                     residual_dtype=self.residual_dtype)
             with jax.named_scope("graphlab.reschedule"):
                 prio, sched = self.scheduler.reschedule(
                     sched, prio, mask, residual, tables=tables,
                     scatter=self._scatter_ctx(tables, sets))
-                if tables is not None and bump is not None:
+                if bump is not None:
                     prio = prio + bump
             with jax.named_scope("graphlab.apply"):
                 count = count + mask.astype(jnp.int32)
@@ -649,14 +609,18 @@ class Engine:
         # stacked sets, so a sweep with hundreds of colors traces, lowers
         # and compiles a handful of phase bodies; the sync op runs safely
         # between phases.
+        # Streaming phases gather over the capacity edge set, filled from
+        # this step's tables (``_stream_sets``).
         if consts["gas"] is None:
+            sets = None if tables is None else self._stream_sets(tables)
+            weights = list(gas_weights[0]) if sets else None
             for phase in range(self.scheduler.num_phases):
-                carry = run_phase(phase, None, carry)
+                carry = run_phase(phase, sets, carry, weights)
         else:
             for g, ((first, n), stacked) in enumerate(
                     zip(self._phase_groups, consts["gas"])):
                 shared = jax.tree.leaves(stacked)[0].shape[0] == 1
-                group_w = gas_weights[g] if gas_weights else ()
+                group_w = gas_weights[g]
 
                 def body(phase, c, first=first, stacked=stacked,
                          shared=shared, group_w=group_w):
@@ -666,7 +630,7 @@ class Engine:
                     e_pad = sets["gather"].senders.shape[0]
                     with jax.named_scope("graphlab.edge_weight"):
                         weights = [jax.lax.dynamic_slice_in_dim(
-                            w, i * e_pad, e_pad) for w in group_w] or None
+                            w, i * e_pad, e_pad) for w in group_w]
                     return run_phase(phase, sets, c, weights)
 
                 if n == 1:

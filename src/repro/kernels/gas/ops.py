@@ -34,7 +34,7 @@ from repro.kernels.gas.ref import gather_combine_ref, scatter_reschedule_ref
 @functools.partial(jax.tree_util.register_dataclass,
                    meta_fields=["n_vertices"],
                    data_fields=["senders", "receivers", "step_rb", "step_eb",
-                                "perm", "block_counts"])
+                                "block_counts"])
 @dataclasses.dataclass(frozen=True, eq=False)
 class EdgeSet:
     """A receiver-sorted edge subset prepared for the GAS kernel.
@@ -47,9 +47,6 @@ class EdgeSet:
     degenerates to one all-padding block): pad senders are 0, pad weights 0,
     pad receivers ``n_vertices + ROW_BLOCK`` (outside every row block).
     ``step_rb``/``step_eb`` are the kernels' flattened grid (``csr_steps``).
-    ``perm`` maps the subset back into the *full* edge arrays so per-edge
-    quantities (weights) evaluated on full edge data can be sliced in-trace
-    (padded with 0: padding edges fall outside every row block anyway).
     ``block_counts[i]`` is the number of real subset edges whose receiver
     lies in row block i — the honest edges-touched accounting unit.
     """
@@ -59,7 +56,6 @@ class EdgeSet:
     receivers: jnp.ndarray            # [E_pad] i32, non-decreasing
     step_rb: jnp.ndarray              # [T] i32 row block of each grid step
     step_eb: jnp.ndarray              # [T] i32 edge block (-1: no-op step)
-    perm: Optional[jnp.ndarray] = None        # [E_pad] into full edge arrays
     block_counts: Optional[jnp.ndarray] = None  # [n_row_blocks] i32
 
     @property
@@ -71,7 +67,6 @@ class EdgeSet:
         senders: np.ndarray,
         receivers: np.ndarray,
         n_vertices: int,
-        perm: Optional[np.ndarray] = None,
         min_blocks: int = 1,
         n_steps: Optional[int] = None,
     ) -> "EdgeSet":
@@ -97,8 +92,6 @@ class EdgeSet:
             n_vertices=int(n_vertices),
             senders=jnp.asarray(s), receivers=jnp.asarray(r),
             step_rb=jnp.asarray(step_rb), step_eb=jnp.asarray(step_eb),
-            perm=None if perm is None else jnp.asarray(
-                np.pad(np.asarray(perm, np.int32), (0, e_pad - E))),
             block_counts=jnp.asarray(counts))
 
 

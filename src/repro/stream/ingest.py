@@ -412,7 +412,8 @@ class _LocalPatcher:
                 vdef, [jnp.asarray(x) for x in vleaves]),
             edge_data=jax.tree.unflatten(
                 edef, [jnp.asarray(x) for x in eleaves]))
-        return state.replace(graph=graph, prio=prio)
+        # the fused weights were prepared from the old tables
+        return state.replace(graph=graph, prio=prio, gas_weights=())
 
 
 # ---------------------------------------------------------------------------

@@ -314,7 +314,7 @@ class StreamingGraph:
 
     def tables(self) -> Dict[str, np.ndarray]:
         """The dynamic structure tables of the local streaming engine
-        (``core/engine_base.py:stream_apply_phase``)."""
+        (``core/engine_base.py``)."""
         nblk = max(-(-self.n_cap // ROW_BLOCK), 1)
         real_recv = self.receivers[self.edge_mask]
         block_counts = np.bincount(

@@ -115,8 +115,8 @@ def test_pagerank_fused_matches_dense_over_calls_and_restart(
 
 
 class TestPreparedWeights:
-    """The fused gather's per-color edge weights are prepared once per edge
-    data (``Engine.prepare_weights``) and carried in the state."""
+    """The fused gather's edge weights are prepared once per edge data
+    (``Engine.prepare_weights``) and carried in the state."""
 
     @staticmethod
     def _weights_count():
@@ -143,33 +143,52 @@ class TestPreparedWeights:
         edata = {k: np.asarray(v) for k, v in g.edge_data.items()}
         full = [np.asarray(leaf.weight(edata)).astype(np.float32)
                 for leaf in leaves]
-        assert len(state.gas_weights) == len(eng._phase_groups)
-        for (first, n), perm, group in zip(eng._phase_groups, eng._gas_perms,
-                                           state.gas_weights):
+        groups, stacked, perms = eng._phase_edge_sets()
+        assert groups == eng._phase_groups
+        assert len(state.gas_weights) == len(groups)
+        for (first, n), sets, perm, group in zip(groups, stacked, perms,
+                                                 state.gas_weights):
             perm = np.asarray(perm)
-            assert perm.shape[0] == n and len(group) == len(leaves)
+            assert perm.shape == sets["gather"].senders.shape == \
+                (n, perm.shape[1])
+            assert len(group) == len(leaves)
             for w, prepared in zip(full, group):
                 assert prepared.dtype == jnp.float32
                 np.testing.assert_array_equal(np.asarray(prepared),
                                               w[perm].reshape(-1))
-        # the step gathers nothing through a perm: the sets it takes carry
-        # none
-        assert all(s["gather"].perm is None for s in eng._consts["gas"])
+        # the step takes the sets as ``_phase_edge_sets`` built them
+        for mine, built in zip(eng._consts["gas"], stacked):
+            for a, b in zip(jax.tree.leaves(mine), jax.tree.leaves(built)):
+                np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     @pytest.mark.parametrize("make", [
-        lambda p, g: BSPEngine(p, g, tolerance=1e-6, use_fused=True),
-        lambda p, g: DynamicEngine(p, g, pipeline_length=64, tolerance=1e-6,
-                                   use_fused=True),
+        lambda p, g, fused=True: BSPEngine(p, g, tolerance=1e-6,
+                                           use_fused=fused),
+        lambda p, g, fused=True: DynamicEngine(
+            p, g, pipeline_length=64, tolerance=1e-6, use_fused=fused),
     ], ids=["bsp", "dynamic"])
-    def test_full_edge_engines_prepare_nothing(self, pagerank_setup, make):
+    def test_full_edge_engines_prepare_once(self, pagerank_setup, make):
+        """An engine whose phases gather over every edge prepares its
+        weights once per edge data: the full-edge weights padded to the
+        set's ``E_pad``, one row all phases share.  Its steps agree with
+        the dense engine's."""
         prog, g = pagerank_setup
         eng = make(prog, g)
         before = self._weights_count()
         state = eng.init(g)
-        assert eng.use_fused and state.gas_weights == ()
-        state, _ = eng.run(state, max_steps=3)
-        assert state.gas_weights == ()
-        assert self._weights_count() == before
+        assert eng.use_fused and self._weights_count() == before + 1
+        (row,), = state.gas_weights
+        e_pad = eng._consts["gas"][0]["gather"].senders.shape[-1]
+        want = np.zeros(e_pad, np.float32)
+        want[:g.n_edges] = np.asarray(g.edge_data["w"], np.float32)
+        np.testing.assert_array_equal(np.asarray(row), want)
+        got, _ = eng.run(state, max_steps=80)
+        assert self._weights_count() == before + 1
+        assert got.gas_weights is state.gas_weights
+        dense = make(prog, g, fused=False)
+        ref, _ = dense.run(dense.init(g), max_steps=80)
+        assert np.abs(np.asarray(got.graph.vertex_data["rank"]) - np.asarray(
+            ref.graph.vertex_data["rank"])).max() <= TOL
 
     def test_replaced_edge_data_is_prepared_again(self):
         prog, g = self._case("pagerank")

@@ -17,6 +17,7 @@ The subsystem's three contracts, each tested directly:
      point: loading and growing are the same operation.
 """
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
@@ -29,11 +30,13 @@ from repro.core import (ChromaticEngine, DataGraph, DynamicEngine, Engine,
                         UnsupportedStreamingError)
 from repro.core.graph import GraphStructure
 from repro.core.partition import build_atoms, overpartition
+from repro.core.update import FusedGather
 from repro.dist import DistributedEngine, DistributedLockingEngine
 from repro.graphs.generators import power_law_graph
 from repro.stream import (AddEdge, AddVertex, CapacityError, DelEdge,
                           DeltaBatch, DeltaJournal, DelVertex,
-                          SetVertexData, SlackConfig, SnapshotInFlightError,
+                          SetEdgeData, SetVertexData, SlackConfig,
+                          SnapshotInFlightError,
                           StreamingGraph, als_rating_arrivals, apply_delta,
                           apply_delta_growing, lbp_arrivals, lbp_churn,
                           make_dist_engine, make_local_engine,
@@ -45,6 +48,19 @@ pytestmark = pytest.mark.skipif(
     "(XLA_FLAGS=--xla_force_host_platform_device_count=4)")
 
 ROOMY = SlackConfig(edge_frac=1.0, edge_min=8)
+
+
+class _DegreePageRank(PageRankProgram):
+    """PageRank on a graph without edge data: each source's rank is split
+    over its live out-degree (``degree_normalized_src``)."""
+
+    def gather(self, ctx):
+        return ctx.src["rank"] / jnp.maximum(
+            ctx.src_deg.astype(jnp.float32), 1.0)
+
+    def fused_gather(self):
+        return FusedGather("degree_normalized_src",
+                           feature=lambda v: v["rank"])
 
 
 def _mesh(n):
@@ -219,6 +235,77 @@ class TestZeroRecompile:
                 state = apply_delta(eng, state, b)
                 state, _ = eng.run(state, max_steps=200)
             assert eng._trace_count == before, cls.__name__
+
+    @pytest.mark.parametrize("kind",
+                             ["delete", "set_edge_data", "no_edge_data"])
+    def test_local_fused_weights_follow_each_batch(self, kind):
+        """The fused streaming engine's edge weights are prepared on the
+        capacity edge data, masked by the live edge mask, with source
+        degrees from the live tables: once per batch, without retracing
+        the step, and the engine reaches the dense streaming engine's
+        fixed point after every batch.  ``no_edge_data`` streams a graph
+        without edge data, whose weights come from the tables alone
+        (``degree_normalized_src``), through an add and a delete batch."""
+        from repro.obs import span_totals
+
+        def preps():
+            total = span_totals().get("graphlab.weights")
+            return total.count if total else 0
+
+        st_ = _connected_power_law(120, 5, 4)
+        order = np.random.default_rng(0).permutation(st_.n_edges)
+        picked = [[(int(st_.senders[e]), int(st_.receivers[e]))
+                   for e in picks] for picks in (order[:6], order[6:12])]
+        if kind == "no_edge_data":
+            g = DataGraph(vertex_data={"rank": jnp.full(
+                st_.n_vertices, 1.0 / st_.n_vertices, jnp.float32)},
+                edge_data=None, structure=st_)
+            prog = _DegreePageRank(0.15, st_.n_vertices)
+            have = set(zip(st_.senders.tolist(), st_.receivers.tolist()))
+            absent = dict.fromkeys(
+                (int(s), int(r)) for s, r in zip(order % 120, order // 7 % 120)
+                if s != r and (s, r) not in have)
+            batches = [DeltaBatch([AddEdge(s, r) for s, r in list(absent)[:6]]),
+                       DeltaBatch([DelEdge(s, r) for s, r in picked[0]])]
+        else:
+            g = make_pagerank_graph(st_)
+            prog = PageRankProgram(0.15, st_.n_vertices)
+            w = np.asarray(g.edge_data["w"])
+            batches = [DeltaBatch(
+                [DelEdge(s, r) for s, r in edges] if kind == "delete" else
+                [SetEdgeData(s, r, {"w": np.float32(0.5 * w[e])})
+                 for (s, r), e in zip(edges, picks)])
+                for edges, picks in zip(picked, (order[:6], order[6:12]))]
+        fused, fs = make_local_engine(prog, g, tolerance=1e-7, slack=ROOMY)
+        dense, ds = make_local_engine(prog, g, tolerance=1e-7, slack=ROOMY,
+                                      use_fused=False)
+        assert fused.use_fused and not dense.use_fused
+        fs, _ = fused.run(fs, max_steps=300)
+        ds, _ = dense.run(ds, max_steps=300)
+        traces = fused._trace_count
+        for b in batches:
+            fs = apply_delta(fused, fs, b)
+            ds = apply_delta(dense, ds, b)
+            assert fs.gas_weights == ()
+            before = preps()
+            fs, _ = fused.run(fs, max_steps=300)
+            ds, _ = dense.run(ds, max_steps=300)
+            assert preps() == before + 1
+            (row,), = fs.gas_weights
+            tables = {k: np.asarray(v) for k, v in fused._tables.items()}
+            if kind == "no_edge_data":
+                deg = tables["out_deg"][tables["senders"]]
+                live = 1.0 / np.maximum(deg.astype(np.float32), 1.0)
+            else:
+                live = np.asarray(fs.graph.edge_data["w"])
+            want = np.where(tables["edge_mask"], live, 0.0)
+            np.testing.assert_array_equal(np.asarray(row)[:want.size], want)
+            assert int(fs.step_index) < 300
+            np.testing.assert_allclose(
+                np.asarray(readback(fused, fs).vertex_data["rank"]),
+                np.asarray(readback(dense, ds).vertex_data["rank"]),
+                rtol=0, atol=1e-5)
+        assert fused._trace_count == traces
 
     def test_small_delta_activates_few_row_blocks(self):
         """The GAS active-block wiring: reconverging a one-edge delta must
